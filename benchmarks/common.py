@@ -15,6 +15,7 @@ from benchmarks import results
 from repro.configs.base import GraphConfig
 from repro.core import engine as E
 from repro.core import graph as G
+from repro.launch.compile_cache import use_compile_cache
 
 
 def emit(name: str, us_per_call: float, derived: str = "", *,
@@ -67,6 +68,7 @@ def bench_cli(area: str, main_fn, smoke_fn=None, argv=None) -> None:
     """Entry point shared by every ``bench_*`` module's ``__main__``:
     picks smoke vs full mode and scopes the run's rows into
     ``BENCH_<area>.json`` (``--out DIR`` overrides the destination)."""
+    use_compile_cache()
     argv = sys.argv[1:] if argv is None else argv
     smoke = "--smoke" in argv and smoke_fn is not None
     out_dir = None

@@ -18,6 +18,7 @@ import time
 import traceback
 
 from benchmarks import results
+from repro.launch.compile_cache import use_compile_cache
 
 
 def modules() -> list:
@@ -34,6 +35,7 @@ def modules() -> list:
 
 
 def main(argv=None) -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
                     help="run each module's smoke subset (CI mode)")

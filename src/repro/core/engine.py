@@ -29,13 +29,13 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import GraphConfig
 from repro.core import programs as prog_mod
 from repro.core.graph import ShardedGraph, build_sharded_graph
 from repro.dist import exchange as ex_mod
-from repro.dist.compat import auto_axis_types, shard_map
 
 N_BUCKETS = 32
 
@@ -1516,7 +1516,7 @@ def lower_tick_for_mesh(cfg: GraphConfig, mesh_2d, n_workers: int):
     """Lower+compile the distributed tick on a 1-D workers view of the
     production mesh (the graph engine shards vertices over every chip)."""
     devs = np.asarray(mesh_2d.devices).reshape(-1)[:n_workers]
-    mesh = Mesh(devs, ("workers",), **auto_axis_types(1))
+    mesh = Mesh(devs, ("workers",), axis_types=(AxisType.Auto,))
     cfg = dataclasses.replace(cfg, num_shards=n_workers)
     prog = prog_mod.get_program(cfg)
     from repro.dist.sharding import vertex_partition

@@ -2,9 +2,6 @@
 
 Four small modules, layered bottom-up:
 
-  * :mod:`repro.dist.compat`      — version-tolerant jax API surface
-    (``shard_map`` moved homes and renamed ``check_rep``/``check_vma``
-    between 0.4.x and 0.6.x; everything in-repo imports it from here).
   * :mod:`repro.dist.sharding`    — *where data lives*: logical-axis ->
     mesh-axis resolution for parameters/activations (``ShardingRules``),
     and the contiguous-range vertex partition used by the graph engine

@@ -90,17 +90,16 @@ def build_pulled_graph(graph: ShardedGraph) -> PulledGraph:
 
 # ======================================================================
 @partial(jax.jit, static_argnames=("semiring", "n_tiles", "use_kernel",
-                                   "use_mxu", "interpret"))
+                                   "use_mxu"))
 def _pull_step(values, edge_src, edge_dst_local, block_tile, weights, *,
                semiring: str, n_tiles: int, use_kernel: bool,
-               use_mxu: bool, interpret: bool):
+               use_mxu: bool):
     ident = _identity(semiring, values.dtype)  # plus_times/SUM: 0
     safe_src = jnp.clip(edge_src, 0, values.shape[0] - 1)
     vals = jnp.where(edge_src >= 0, values[safe_src], ident)
     if use_kernel:
         partials = spmv_partials(vals, edge_dst_local, weights,
-                                 semiring=semiring, use_mxu=use_mxu,
-                                 interpret=interpret)
+                                 semiring=semiring, use_mxu=use_mxu)
     else:
         partials = ref_mod.spmv_partials_ref(vals, edge_dst_local, weights,
                                              semiring=semiring)
@@ -114,8 +113,7 @@ def _pull_step(values, edge_src, edge_dst_local, block_tile, weights, *,
 
 def frontier_pull_step(values: jnp.ndarray, pg: PulledGraph, *,
                        semiring: str, use_kernel: bool = True,
-                       use_mxu: bool = False,
-                       interpret: bool = True) -> jnp.ndarray:
+                       use_mxu: bool = False) -> jnp.ndarray:
     """One full propagation: out[v] = reduce over in-edges combine(src, w).
 
     For idempotent semirings the result is further tied against the
@@ -130,8 +128,7 @@ def frontier_pull_step(values: jnp.ndarray, pg: PulledGraph, *,
                      jnp.asarray(pg.block_tile),
                      jnp.asarray(pg.weights) if pg.weights is not None else None,
                      semiring=semiring, n_tiles=pg.n_tiles,
-                     use_kernel=use_kernel, use_mxu=use_mxu,
-                     interpret=interpret)
+                     use_kernel=use_kernel, use_mxu=use_mxu)
     agg = for_semiring(semiring)
     if agg.idempotent:
         out = agg.tie(out, v)
@@ -141,7 +138,7 @@ def frontier_pull_step(values: jnp.ndarray, pg: PulledGraph, *,
 # ======================================================================
 def pagerank(graph: ShardedGraph, *, damping: float = 0.85,
              iters: int = 30, use_kernel: bool = True,
-             interpret: bool = True, dangling: str = "redistribute"):
+             dangling: str = "redistribute"):
     """PageRank in the paper's §3.3-safe formulation.
 
     A push-mode asynchronous PageRank with (+) messages is NOT idempotent —
@@ -175,8 +172,7 @@ def pagerank(graph: ShardedGraph, *, damping: float = 0.85,
     for _ in range(iters):
         contrib = rank / deg_j
         pulled = frontier_pull_step(contrib, pg, semiring="plus_times",
-                                    use_kernel=use_kernel,
-                                    interpret=interpret)
+                                    use_kernel=use_kernel)
         if dangling == "redistribute":
             dm = jnp.sum(jnp.where(dangling_mask, rank, 0.0))
             pulled = pulled + dm / n_real
@@ -187,7 +183,7 @@ def pagerank(graph: ShardedGraph, *, damping: float = 0.85,
 
 # ======================================================================
 def bsp_connected_components(graph: ShardedGraph, *, use_kernel: bool = True,
-                             interpret: bool = True, max_rounds: int = 10000):
+                             max_rounds: int = 10000):
     """Synchronous full-frontier CC (the Pregel-equivalent BSP baseline).
 
     Runs min-label propagation rounds until fixpoint; each round is one
@@ -201,7 +197,7 @@ def bsp_connected_components(graph: ShardedGraph, *, use_kernel: bool = True,
     messages = 0
     for _ in range(max_rounds):
         new = frontier_pull_step(values, pg, semiring="min",
-                                 use_kernel=use_kernel, interpret=interpret)
+                                 use_kernel=use_kernel)
         rounds += 1
         messages += int(pg.edge_src.shape[0])  # BSP sends on every edge
         if bool(jnp.all(new == values)):

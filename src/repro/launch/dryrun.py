@@ -20,10 +20,14 @@ Usage:
 """
 from __future__ import annotations
 
-# The 512 placeholder devices MUST be claimed before any other import —
-# jax locks the device count on first initialization.
+# A CPU-only structural tool: its 512 placeholder devices are CPU devices,
+# and both settings MUST precede any jax import — jax locks the platform
+# and the device count on first initialization.
 import os  # noqa: E402
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512")))
 
 import argparse
 import json

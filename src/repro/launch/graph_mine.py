@@ -16,9 +16,12 @@ per-tick metrics log.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
-import os
 import time
+from typing import NamedTuple, Optional, Sequence
+
+import numpy as np
 
 from repro.configs import get_graph_config
 from repro.core import engine as E
@@ -27,9 +30,21 @@ from repro.core import merger
 from repro.core import programs as PR
 from repro.core.faults import FaultPlan
 from repro.dist import latency as lat_mod
+from repro.launch.compile_cache import use_compile_cache
 
 
-def main() -> None:
+class MineRun(NamedTuple):
+    """What one job produced, for callers that run :func:`main` in-process."""
+    graph: G.ShardedGraph
+    state: E.EngineState
+    totals: dict  # run_to_convergence's metrics (ticks, converged, ...)
+    out: np.ndarray  # the merger's per-vertex output table
+    build_s: float  # host CSR build
+    propagate_s: float  # propagation phase, compile included
+
+
+def main(argv: Optional[Sequence[str]] = None) -> MineRun:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="asymp_cc")
     ap.add_argument("--algorithm", default=None, choices=sorted(PR.PROGRAMS),
@@ -62,10 +77,9 @@ def main() -> None:
                          "(CI smoke)")
     ap.add_argument("--out", default="")
     ap.add_argument("--metrics", default="")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_graph_config(args.config)
-    import dataclasses
     kw = {}
     if args.priority:
         kw["priority"] = args.priority
@@ -106,7 +120,8 @@ def main() -> None:
           f"schedule={cfg.schedule}")
     t0 = time.time()
     graph = G.build_sharded_graph(cfg)
-    print(f"[graph_mine] built CSR in {time.time() - t0:.1f}s "
+    build_s = time.time() - t0
+    print(f"[graph_mine] built CSR in {build_s:.1f}s "
           f"({graph.num_edges} directed edges after symmetrize)")
 
     plan = (FaultPlan(fail_fraction=args.failures, start_tick=4, every=6)
@@ -132,7 +147,6 @@ def main() -> None:
     if args.metrics:
         with open(args.metrics, "w") as f:
             json.dump({k: v for k, v in totals.items()}, f, indent=1)
-    import numpy as np
     if cfg.algorithm in ("cc", "labelprop"):
         summary = f"components={len(np.unique(out))}"
     elif cfg.algorithm == "reachability":
@@ -153,6 +167,7 @@ def main() -> None:
                    else f"reached={int(reached.sum())}")
     print(f"[graph_mine] merger ({prog.name}): {len(out)} vertices, "
           f"{summary}")
+    return MineRun(graph, state, totals, out, build_s, wall)
 
 
 if __name__ == "__main__":

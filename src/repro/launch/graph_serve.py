@@ -20,16 +20,29 @@ import argparse
 import dataclasses
 import json
 import time
+from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
 from repro.configs import get_graph_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve.engine import QueueFullError
 from repro.serve.graph import (KIND_PROGRAM, GraphQuery, GraphServer,
                                QueryServer)
 
 
-def main() -> None:
+class ServeRun(NamedTuple):
+    """What one serving run produced, for callers that run :func:`main`
+    in-process."""
+    server: GraphServer  # after the deltas: patched graph, latest epoch
+    answers: dict  # rid -> answer (typed DeadlineExceeded when overdue)
+    admission: dict  # QueryServer.stats()
+    build_s: float  # GraphServer construction: CSR build + sessions
+    converge_s: float  # first convergence of every program
+
+
+def main(argv: Optional[Sequence[str]] = None) -> ServeRun:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="asymp_cc")
     ap.add_argument("--programs", default="cc,sssp,pagerank",
@@ -62,7 +75,7 @@ def main() -> None:
                     help="override max_ticks (push-mode convergence "
                          "budget)")
     ap.add_argument("--metrics", default="")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_graph_config(args.config)
     if args.reduced:
@@ -80,15 +93,18 @@ def main() -> None:
     print(f"[graph_serve] {cfg.name}: programs={','.join(programs)} "
           f"V={cfg.num_vertices} E~{cfg.num_edges} shards={cfg.num_shards} "
           f"schedule={cfg.schedule} store={args.store or '<live>'}")
+    t0 = time.time()
     srv = GraphServer(cfg, programs=programs, store_dir=args.store or None,
                       schedule=args.schedule)
+    build_s = time.time() - t0
     t0 = time.time()
     totals = srv.converge()
+    converge_s = time.time() - t0
     for name, tot in totals.items():
         print(f"[graph_serve] {name}: {tot['ticks']} ticks, "
               f"converged={tot['converged']}")
     print(f"[graph_serve] converged {len(programs)} programs in "
-          f"{time.time() - t0:.1f}s; epoch={srv.epoch}")
+          f"{converge_s:.1f}s; epoch={srv.epoch}")
     stuck = [n for n, tot in totals.items() if not tot["converged"]]
     if stuck:
         raise SystemExit(
@@ -157,7 +173,7 @@ def main() -> None:
                        "epoch": srv.epoch, "deltas": delta_rows,
                        "admission": qs.stats()}, f, indent=1)
         print(f"[graph_serve] wrote metrics to {args.metrics}")
-    del done
+    return ServeRun(srv, done, qs.stats(), build_s, converge_s)
 
 
 if __name__ == "__main__":

@@ -9,9 +9,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-from repro.dist.compat import auto_axis_types
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -19,7 +17,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     a leading "pod" axis used as an outer data-parallel dimension."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model: int = 1) -> Mesh:
@@ -28,7 +27,7 @@ def make_local_mesh(model: int = 1) -> Mesh:
     n = devs.size
     assert n % model == 0, (n, model)
     return Mesh(devs.reshape(n // model, model), ("data", "model"),
-                **auto_axis_types(2))
+                axis_types=(AxisType.Auto,) * 2)
 
 
 def make_worker_mesh(num_workers: int | None = None) -> Mesh:

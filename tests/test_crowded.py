@@ -27,7 +27,7 @@ from repro.core import programs as PR
 from repro.core.faults import FaultPlan, apply_slowdown, max_injected_delay
 from repro.dist import exchange as X
 from repro.dist import latency as L
-from repro.dist.compat import shard_map
+from jax import shard_map
 
 from conftest import csr_edges
 

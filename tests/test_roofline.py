@@ -39,7 +39,7 @@ class TestCollectiveParser:
     def test_real_compiled_module(self):
         """Parser agrees with a real lowered psum."""
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.dist.compat import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
 
         def f(x):

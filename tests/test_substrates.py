@@ -135,7 +135,7 @@ class TestCompression:
         """int8 EF all-reduce ~= exact mean; error feedback is carried."""
         devs = jax.devices()
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.dist.compat import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(devs[:1]), ("d",))
         g = jax.random.normal(jax.random.PRNGKey(1), (512,)) * 0.1
 
@@ -302,7 +302,7 @@ class TestExchange:
         """Same codec, both transports, bit-identical delivery."""
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.dist import exchange as X
-        from repro.dist.compat import shard_map
+        from jax import shard_map
         codec = X.make_wire_codec(num_shards=1, capacity=8, vs=64,
                                   requested="int16", value_kind="int32",
                                   identity=2 ** 31 - 1, max_int_value=64,
