@@ -1,0 +1,7 @@
+"""Device time of the ops under ``tick.fetch`` (adjacency fetch and message
+creation), per tick of the traced window, in ms."""
+from chipbench import program_trace
+
+
+def read(ctx):
+    return program_trace.phase_ms(ctx, "tick.fetch")

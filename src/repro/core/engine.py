@@ -34,6 +34,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import GraphConfig
 from repro.core import programs as prog_mod
+from repro.core import trace
 from repro.core.graph import ShardedGraph, build_sharded_graph
 from repro.dist import exchange as ex_mod
 
@@ -197,163 +198,170 @@ def _phase1_create(prog, ep: EngineParams, values, active, cursor,
     if push_mode:
         residual, pushv = aux[0], aux[1]
 
-    # ---- select (priority queue with enforcement fraction) ----
-    # Sort-free selection (§Perf iter G1): bucket histogram + cumsum
-    # threshold + rank-by-cumsum replaces a [vs] argsort — the paper's
-    # bucketed queues never needed total order anyway.
-    n_active = jnp.sum(active)
-    m_eff = (M if throttle is None
-             else jnp.maximum(M // jnp.maximum(throttle, 1), 1))
-    target = jnp.clip(jnp.ceil(ep.enforce_fraction * n_active), 1, m_eff
-                      ).astype(jnp.int32)
-    # the aggregator orients the program's raw potential metric into an
-    # ascending key (min: low value first; max/or: high value first;
-    # sum: most pending mass — residual + latched push — first)
-    pmetric = (prog.priority_value(residual + pushv) if push_mode
-               else prog.priority_value(values))
-    pkey = prog.aggregator.priority_key(pmetric, ep.priority_scale)
-    buckets = priority_buckets(pkey, ep.priority, ep.priority_scale)
-    if demote is not None and ep.straggler_demote:
-        buckets = jnp.where(
-            demote, jnp.minimum(buckets + ep.straggler_demote,
-                                N_BUCKETS - 1), buckets)
-    hist = jnp.zeros((N_BUCKETS,), jnp.int32).at[buckets].add(
-        active.astype(jnp.int32))
-    cum = jnp.cumsum(hist)
-    thr = jnp.searchsorted(cum, target)  # first bucket covering the target
-    # strict two-tier rank: every vertex in buckets < thr outranks the
-    # threshold bucket (within a bucket, index order — the paper's queues
-    # are unordered within a bucket too)
-    low = active & (buckets < thr)
-    at_thr = active & (buckets == thr)
-    n_low = jnp.cumsum(low.astype(jnp.int32))
-    n_thr = jnp.cumsum(at_thr.astype(jnp.int32))
-    total_low = n_low[-1]
-    rank_v = jnp.where(low, n_low - 1, total_low + n_thr - 1)
-    pre = low | at_thr
-    sel_mask = pre & (rank_v < jnp.minimum(target, M))
-    # invalid slots get the out-of-bounds sentinel `vs` so downstream
-    # scatters drop them (slot-0 fill would alias a real vertex)
-    sel = jnp.full((M,), vs, jnp.int32).at[
-        jnp.where(sel_mask, rank_v, M)].set(jnp.arange(vs, dtype=jnp.int32),
-                                            mode="drop")
-    sel_valid = jnp.zeros((M,), bool).at[
-        jnp.where(sel_mask, rank_v, M)].set(True, mode="drop")
-    # overflow slots go to the best buckets first: the two-tier rank above
-    # is vertex-index order WITHIN each tier, and the routing rank below is
-    # a stable sort over flat slot order — so under starved route capacity
-    # the kept prefix used to be the low-vertex-index work, not the
-    # high-priority work (backpressured pagerank lost its big-mass-first
-    # schedule).  A stable argsort over the M slots by bucket restores the
-    # priority order; with priority disabled every bucket is 0 and the
-    # permutation is the identity (FIFO semantics untouched).
-    slot_bucket = jnp.where(sel_valid, buckets[jnp.minimum(sel, vs - 1)],
-                            N_BUCKETS)
-    reorder = jnp.argsort(slot_bucket)  # stable; invalid slots sort last
-    sel = sel[reorder]
-    sel_valid = sel_valid[reorder]
-    sel_safe = jnp.minimum(sel, vs - 1)  # for gathers
+    with jax.named_scope("tick.select"):
+        # ---- select (priority queue with enforcement fraction) ----
+        # Sort-free selection (§Perf iter G1): bucket histogram + cumsum
+        # threshold + rank-by-cumsum replaces a [vs] argsort — the paper's
+        # bucketed queues never needed total order anyway.
+        n_active = jnp.sum(active)
+        m_eff = (M if throttle is None
+                 else jnp.maximum(M // jnp.maximum(throttle, 1), 1))
+        target = jnp.clip(jnp.ceil(ep.enforce_fraction * n_active), 1, m_eff
+                          ).astype(jnp.int32)
+        # the aggregator orients the program's raw potential metric into an
+        # ascending key (min: low value first; max/or: high value first;
+        # sum: most pending mass — residual + latched push — first)
+        pmetric = (prog.priority_value(residual + pushv) if push_mode
+                   else prog.priority_value(values))
+        pkey = prog.aggregator.priority_key(pmetric, ep.priority_scale)
+        buckets = priority_buckets(pkey, ep.priority, ep.priority_scale)
+        if demote is not None and ep.straggler_demote:
+            buckets = jnp.where(
+                demote, jnp.minimum(buckets + ep.straggler_demote,
+                                    N_BUCKETS - 1), buckets)
+        hist = jnp.zeros((N_BUCKETS,), jnp.int32).at[buckets].add(
+            active.astype(jnp.int32))
+        cum = jnp.cumsum(hist)
+        thr = jnp.searchsorted(cum, target)  # first bucket covering the target
+        # strict two-tier rank: every vertex in buckets < thr outranks the
+        # threshold bucket (within a bucket, index order — the paper's queues
+        # are unordered within a bucket too)
+        low = active & (buckets < thr)
+        at_thr = active & (buckets == thr)
+        n_low = jnp.cumsum(low.astype(jnp.int32))
+        n_thr = jnp.cumsum(at_thr.astype(jnp.int32))
+        total_low = n_low[-1]
+        rank_v = jnp.where(low, n_low - 1, total_low + n_thr - 1)
+        pre = low | at_thr
+        sel_mask = pre & (rank_v < jnp.minimum(target, M))
+        # invalid slots get the out-of-bounds sentinel `vs` so downstream
+        # scatters drop them (slot-0 fill would alias a real vertex)
+        sel = jnp.full((M,), vs, jnp.int32).at[
+            jnp.where(sel_mask, rank_v, M)].set(
+            jnp.arange(vs, dtype=jnp.int32), mode="drop")
+        sel_valid = jnp.zeros((M,), bool).at[
+            jnp.where(sel_mask, rank_v, M)].set(True, mode="drop")
+        # overflow slots go to the best buckets first: the two-tier rank
+        # above is vertex-index order WITHIN each tier, and the routing rank
+        # below is a stable sort over flat slot order — so under starved
+        # route capacity the kept prefix used to be the low-vertex-index
+        # work, not the high-priority work (backpressured pagerank lost its
+        # big-mass-first schedule).  A stable argsort over the M slots by
+        # bucket restores the priority order; with priority disabled every
+        # bucket is 0 and the permutation is the identity (FIFO semantics
+        # untouched).
+        slot_bucket = jnp.where(sel_valid, buckets[jnp.minimum(sel, vs - 1)],
+                                N_BUCKETS)
+        reorder = jnp.argsort(slot_bucket)  # stable; invalid slots sort last
+        sel = sel[reorder]
+        sel_valid = sel_valid[reorder]
+        sel_safe = jnp.minimum(sel, vs - 1)  # for gathers
 
-    # ---- fetch adjacency window (streamed via cursor) ----
-    deg = (row_ptr[sel_safe + 1] - row_ptr[sel_safe]).astype(jnp.int32)
-    cur = cursor[sel_safe]
-    base = row_ptr[sel_safe].astype(jnp.int32) + cur
-    offs = jnp.arange(D, dtype=jnp.int32)
-    eidx = base[:, None] + offs[None, :]
-    edge_valid = sel_valid[:, None] & ((cur[:, None] + offs[None, :])
-                                       < deg[:, None])
-    if stream_window is not None:
-        edge_valid = edge_valid & (offs[None, :] < stream_window)
-    eidx_safe = jnp.clip(eidx, 0, col_idx.shape[0] - 1)
-    dst = jnp.where(edge_valid, col_idx[eidx_safe], -1)  # global ids
-    w = weights[eidx_safe] if weights is not None else None
+    with jax.named_scope("tick.fetch"):
+        # ---- fetch adjacency window (streamed via cursor) ----
+        deg = (row_ptr[sel_safe + 1] - row_ptr[sel_safe]).astype(jnp.int32)
+        cur = cursor[sel_safe]
+        base = row_ptr[sel_safe].astype(jnp.int32) + cur
+        offs = jnp.arange(D, dtype=jnp.int32)
+        eidx = base[:, None] + offs[None, :]
+        edge_valid = sel_valid[:, None] & ((cur[:, None] + offs[None, :])
+                                           < deg[:, None])
+        if stream_window is not None:
+            edge_valid = edge_valid & (offs[None, :] < stream_window)
+        eidx_safe = jnp.clip(eidx, 0, col_idx.shape[0] - 1)
+        dst = jnp.where(edge_valid, col_idx[eidx_safe], -1)  # global ids
+        w = weights[eidx_safe] if weights is not None else None
 
-    # ---- create messages ----
-    if push_mode:
-        # latch: a selected vertex not already mid-push moves its
-        # residual into the outgoing latch and banks it into the output
-        # value — exactly once per push.  Mid-push means a nonzero latch
-        # OR a nonzero cursor: a zero-mass push (selected while the
-        # residual is exactly 0, e.g. restart-personalized pagerank
-        # where init activates every vertex) streams its adjacency with
-        # latch == 0, and re-latching mid-stream would resume at the
-        # cursor and ship the new mass over only the tail of the edge
-        # list, silently losing the head's share.
-        latch = sel_valid & (pushv[sel_safe] == 0) & (cur == 0)
-        mass = jnp.where(latch, residual[sel_safe], pushv[sel_safe])  # [M]
-        msg = jnp.broadcast_to(
-            prog.combine(mass[:, None], w, deg[:, None]), (M, D))
-    else:
-        msg = jnp.broadcast_to(prog.combine(values[sel_safe][:, None], w),
-                               (M, D))
+        # ---- create messages ----
+        if push_mode:
+            # latch: a selected vertex not already mid-push moves its
+            # residual into the outgoing latch and banks it into the output
+            # value — exactly once per push.  Mid-push means a nonzero latch
+            # OR a nonzero cursor: a zero-mass push (selected while the
+            # residual is exactly 0, e.g. restart-personalized pagerank
+            # where init activates every vertex) streams its adjacency with
+            # latch == 0, and re-latching mid-stream would resume at the
+            # cursor and ship the new mass over only the tail of the edge
+            # list, silently losing the head's share.
+            latch = sel_valid & (pushv[sel_safe] == 0) & (cur == 0)
+            mass = jnp.where(latch, residual[sel_safe], pushv[sel_safe])  # [M]
+            msg = jnp.broadcast_to(
+                prog.combine(mass[:, None], w, deg[:, None]), (M, D))
+        else:
+            msg = jnp.broadcast_to(prog.combine(values[sel_safe][:, None], w),
+                                   (M, D))
 
-    # ---- route: bucket by destination shard, bounded capacity ----
-    dst_shard = jnp.where(dst >= 0, dst // vs, Pn)  # Pn = invalid bucket
-    flat_shard = dst_shard.reshape(-1)
-    order2 = jnp.argsort(flat_shard)
-    so = flat_shard[order2]
-    starts = jnp.searchsorted(so, jnp.arange(Pn + 1))
-    rank_sorted = jnp.arange(flat_shard.shape[0]) - starts[so]
-    inv = jnp.zeros_like(order2).at[order2].set(jnp.arange(order2.shape[0]))
-    rank = rank_sorted[inv].reshape(M, D)
+    with jax.named_scope("tick.route"):
+        # ---- route: bucket by destination shard, bounded capacity ----
+        dst_shard = jnp.where(dst >= 0, dst // vs, Pn)  # Pn = invalid bucket
+        flat_shard = dst_shard.reshape(-1)
+        order2 = jnp.argsort(flat_shard)
+        so = flat_shard[order2]
+        starts = jnp.searchsorted(so, jnp.arange(Pn + 1))
+        rank_sorted = jnp.arange(flat_shard.shape[0]) - starts[so]
+        inv = jnp.zeros_like(order2).at[order2].set(
+            jnp.arange(order2.shape[0]))
+        rank = rank_sorted[inv].reshape(M, D)
 
-    keep = edge_valid & (rank < cap)
-    # first routing drop per vertex — the cursor stops there and retries
-    dropped = edge_valid & ~keep
-    any_drop = dropped.any(axis=1)
-    first_drop = jnp.where(any_drop, jnp.argmax(dropped, axis=1), D)
-    if stream_window is not None:
-        # the cursor must stop at the window even with no routing drop:
-        # edges past it were never fetched this call
-        first_drop = jnp.minimum(first_drop, stream_window)
-    if push_mode:
-        # exactly-once: ship ONLY the contiguous prefix the cursor will
-        # advance past.  A kept edge after the first drop is re-fetched
-        # when the cursor resumes — idempotent reduces absorb that
-        # duplicate, a SUM would count the mass twice.
-        keep = keep & (offs[None, :] < first_drop[:, None])
-    r_safe = jnp.where(keep, rank, cap)  # cap = out of bounds -> dropped
-    ds_safe = jnp.where(keep, dst_shard, 0)
-    send_vals = jnp.full((Pn, cap), prog.identity, prog.jdtype).at[
-        ds_safe.reshape(-1), r_safe.reshape(-1)].set(
-        msg.reshape(-1).astype(prog.jdtype), mode="drop")
-    send_ids = jnp.full((Pn, cap), -1, jnp.int32).at[
-        ds_safe.reshape(-1), r_safe.reshape(-1)].set(
-        jnp.where(keep, dst % vs, -1).reshape(-1).astype(jnp.int32),
-        mode="drop")
-
-    # ---- cursor advance: up to the first dropped edge (retry the rest) ----
-    advance = jnp.minimum(first_drop.astype(jnp.int32), deg - cur)
-    new_cur = cur + jnp.where(sel_valid, advance, 0)
-    done = sel_valid & (new_cur >= deg)
-    upd_idx = jnp.where(sel_valid, sel, vs)  # OOB -> dropped
-    cursor = cursor.at[upd_idx].set(jnp.where(done, 0, new_cur), mode="drop")
-    if push_mode:
-        res_after = jnp.where(latch, 0.0, residual[sel_safe]).astype(
-            prog.jdtype)
-        values = values.at[upd_idx].add(
-            jnp.where(latch, mass, 0.0).astype(prog.jdtype), mode="drop")
-        residual = residual.at[upd_idx].set(res_after, mode="drop")
-        pushv = pushv.at[upd_idx].set(
-            jnp.where(done, 0.0, mass).astype(prog.jdtype), mode="drop")
-        # a finished push retires; it re-arms iff mass accumulated while
-        # the stream was in flight (receives do NOT touch the cursor in
-        # push mode, so only this site may conclude a push).  abs: delta
-        # corrections (serve/graph) inject signed mass, and a negative
-        # residual must drain just like a positive one — identical for
-        # ordinary runs, whose residuals never go negative.
-        active = active.at[upd_idx].set(
-            jnp.where(done, jnp.abs(res_after) > prog.push_eps, True),
+        keep = edge_valid & (rank < cap)
+        # first routing drop per vertex — the cursor stops there and retries
+        dropped = edge_valid & ~keep
+        any_drop = dropped.any(axis=1)
+        first_drop = jnp.where(any_drop, jnp.argmax(dropped, axis=1), D)
+        if stream_window is not None:
+            # the cursor must stop at the window even with no routing drop:
+            # edges past it were never fetched this call
+            first_drop = jnp.minimum(first_drop, stream_window)
+        if push_mode:
+            # exactly-once: ship ONLY the contiguous prefix the cursor will
+            # advance past.  A kept edge after the first drop is re-fetched
+            # when the cursor resumes — idempotent reduces absorb that
+            # duplicate, a SUM would count the mass twice.
+            keep = keep & (offs[None, :] < first_drop[:, None])
+        r_safe = jnp.where(keep, rank, cap)  # cap = out of bounds -> dropped
+        ds_safe = jnp.where(keep, dst_shard, 0)
+        send_vals = jnp.full((Pn, cap), prog.identity, prog.jdtype).at[
+            ds_safe.reshape(-1), r_safe.reshape(-1)].set(
+            msg.reshape(-1).astype(prog.jdtype), mode="drop")
+        send_ids = jnp.full((Pn, cap), -1, jnp.int32).at[
+            ds_safe.reshape(-1), r_safe.reshape(-1)].set(
+            jnp.where(keep, dst % vs, -1).reshape(-1).astype(jnp.int32),
             mode="drop")
-        aux = jnp.stack([residual, pushv])
-    else:
-        active = active.at[upd_idx].set(~done, mode="drop")
+
+        # ---- cursor advance: up to the first dropped edge (retry the rest)
+        advance = jnp.minimum(first_drop.astype(jnp.int32), deg - cur)
+        new_cur = cur + jnp.where(sel_valid, advance, 0)
+        done = sel_valid & (new_cur >= deg)
+        upd_idx = jnp.where(sel_valid, sel, vs)  # OOB -> dropped
+        cursor = cursor.at[upd_idx].set(jnp.where(done, 0, new_cur),
+                                        mode="drop")
+        if push_mode:
+            res_after = jnp.where(latch, 0.0, residual[sel_safe]).astype(
+                prog.jdtype)
+            values = values.at[upd_idx].add(
+                jnp.where(latch, mass, 0.0).astype(prog.jdtype), mode="drop")
+            residual = residual.at[upd_idx].set(res_after, mode="drop")
+            pushv = pushv.at[upd_idx].set(
+                jnp.where(done, 0.0, mass).astype(prog.jdtype), mode="drop")
+            # a finished push retires; it re-arms iff mass accumulated while
+            # the stream was in flight (receives do NOT touch the cursor in
+            # push mode, so only this site may conclude a push).  abs: delta
+            # corrections (serve/graph) inject signed mass, and a negative
+            # residual must drain just like a positive one — identical for
+            # ordinary runs, whose residuals never go negative.
+            active = active.at[upd_idx].set(
+                jnp.where(done, jnp.abs(res_after) > prog.push_eps, True),
+                mode="drop")
+            aux = jnp.stack([residual, pushv])
+        else:
+            active = active.at[upd_idx].set(~done, mode="drop")
 
     sent = jnp.sum(keep)
     fetched = jnp.sum(edge_valid)
     return active, cursor, send_vals, send_ids, sent, fetched, values, aux
 
 
+@jax.named_scope("tick.receive")
 def _phase2_receive(prog, ep: EngineParams, values, active, cursor,
                     recv_vals, recv_ids):
     """Deliver: idempotent scatter-⊕ (the program's aggregator); improved
@@ -374,6 +382,7 @@ def _phase2_receive(prog, ep: EngineParams, values, active, cursor,
     return values, active, cursor, accepted
 
 
+@jax.named_scope("tick.receive")
 def _phase2_receive_push(prog, ep: EngineParams, residual, active,
                          recv_vals, recv_ids):
     """Push-mode delivery: scatter-ADD into the residual plane (the SUM
@@ -1162,27 +1171,30 @@ class EngineSession:
         # of device time — keyed on the host step, the pattern would
         # shift across a restore and a due-but-unconsumed row could
         # be overwritten, silently dropping in-flight messages
-        dev_tick = int(self._astate.core.tick)
+        with trace.span(trace.SYNC, pulls=1):
+            dev_tick = int(self._astate.core.tick)
         delays, throttle = self._faults.apply_slowdown(
             fault_plan, dev_tick, self._base_delays, self._base_throttle)
         fire = self._inter.fire_mask(dev_tick, rates=throttle)
         window = jnp.asarray(
             np.minimum(np.asarray(throttle, np.int64), self._r_all)
             * self._D_base, jnp.int32)
-        astate, astats, send_bufs = self._tick_fn(
-            self._astate, self.g,
-            jnp.asarray(np.minimum(delays, self.max_delay), jnp.int32),
-            jnp.asarray(fire), window)
+        with trace.span(trace.DISPATCH):
+            astate, astats, send_bufs = self._tick_fn(
+                self._astate, self.g,
+                jnp.asarray(np.minimum(delays, self.max_delay), jnp.int32),
+                jnp.asarray(fire), window)
         stats = astats.base
-        n_active = int(stats.active)
-        pending = int(astats.pending)
-        shard_busy = (np.asarray(astats.shard_active)
-                      + np.asarray(astats.shard_pending))
         totals = self.totals
-        totals["ticks"] += 1
-        totals["sent"] += int(stats.sent)
-        totals["accepted"] += int(stats.accepted)
-        totals["fetched"] += int(stats.fetched)
+        with trace.span(trace.SYNC, pulls=7):
+            n_active = int(stats.active)
+            pending = int(astats.pending)
+            shard_busy = (np.asarray(astats.shard_active)
+                          + np.asarray(astats.shard_pending))
+            totals["ticks"] += 1
+            totals["sent"] += int(stats.sent)
+            totals["accepted"] += int(stats.accepted)
+            totals["fetched"] += int(stats.fetched)
         if fault_mgr is not None:
             fault_mgr.record(t, astate.core, send_bufs,
                              clock=astate.clock)
@@ -1213,31 +1225,35 @@ class EngineSession:
                         self._ring_delay)._replace(
                         core=core._replace(
                             tick=jnp.zeros((), jnp.int32)))
-                pending = int(jnp.sum(
-                    (astate.ring.ids >= 0)
-                    & (astate.ring.due >= 0)[..., None]))
+                with trace.span(trace.SYNC, pulls=1):
+                    pending = int(jnp.sum(
+                        (astate.ring.ids >= 0)
+                        & (astate.ring.due >= 0)[..., None]))
             totals["replayed"] += extra.get("replayed", 0)
             totals["failures"] += extra.get("failures", 0)
             if extra.get("failures"):
-                n_active = int(jnp.sum(astate.core.active))
-                shard_busy = (
-                    np.asarray(jnp.sum(astate.core.active, axis=1))
-                    + np.asarray(jnp.sum(
-                        (astate.ring.ids >= 0)
-                        & (astate.ring.due >= 0)[..., None],
-                        axis=(0, 1, 3))))
+                with trace.span(trace.SYNC, pulls=3):
+                    n_active = int(jnp.sum(astate.core.active))
+                    shard_busy = (
+                        np.asarray(jnp.sum(astate.core.active, axis=1))
+                        + np.asarray(jnp.sum(
+                            (astate.ring.ids >= 0)
+                            & (astate.ring.due >= 0)[..., None],
+                            axis=(0, 1, 3))))
         if self.collect_log:
-            self.log.append({
-                "tick": t, "active": n_active,
-                "sent": int(stats.sent),
-                "accepted": int(stats.accepted),
-                "fetched": int(stats.fetched), "pending": pending,
-                "fired": np.asarray(fire).astype(int).tolist(),
-                "clock": np.asarray(astate.clock).tolist(),
-                "shard_active": np.asarray(
-                    astats.shard_active).tolist(),
-                "shard_pending": np.asarray(
-                    astats.shard_pending).tolist()})
+            # the clock; the rest were pulled above, ``fire`` is host numpy
+            with trace.span(trace.SYNC, pulls=1):
+                self.log.append({
+                    "tick": t, "active": n_active,
+                    "sent": int(stats.sent),
+                    "accepted": int(stats.accepted),
+                    "fetched": int(stats.fetched), "pending": pending,
+                    "fired": np.asarray(fire).astype(int).tolist(),
+                    "clock": np.asarray(astate.clock).tolist(),
+                    "shard_active": np.asarray(
+                        astats.shard_active).tolist(),
+                    "shard_pending": np.asarray(
+                        astats.shard_pending).tolist()})
         self._astate = astate
         self._n_active = n_active
         self._pending = pending
@@ -1247,18 +1263,20 @@ class EngineSession:
         t, fault_plan, fault_mgr = self._t, self.fault_plan, self.fault_mgr
         delays, throttle = self._faults.apply_slowdown(
             fault_plan, t, self._base_delays, self._base_throttle)
-        cstate, cstats, send_bufs = self._tick_fn(
-            self._cstate, self.g,
-            jnp.asarray(np.minimum(delays, self.max_delay), jnp.int32),
-            jnp.asarray(throttle, jnp.int32))
+        with trace.span(trace.DISPATCH):
+            cstate, cstats, send_bufs = self._tick_fn(
+                self._cstate, self.g,
+                jnp.asarray(np.minimum(delays, self.max_delay), jnp.int32),
+                jnp.asarray(throttle, jnp.int32))
         stats = cstats.base
-        n_active = int(stats.active)
-        pending = int(cstats.pending)
         totals = self.totals
-        totals["ticks"] += 1
-        totals["sent"] += int(stats.sent)
-        totals["accepted"] += int(stats.accepted)
-        totals["fetched"] += int(stats.fetched)
+        with trace.span(trace.SYNC, pulls=5):
+            n_active = int(stats.active)
+            pending = int(cstats.pending)
+            totals["ticks"] += 1
+            totals["sent"] += int(stats.sent)
+            totals["accepted"] += int(stats.accepted)
+            totals["fetched"] += int(stats.fetched)
         if fault_mgr is not None:
             fault_mgr.record(t, cstate.core, send_bufs)
             if (fault_mgr.recovery == "checkpoint"
@@ -1287,43 +1305,50 @@ class EngineSession:
                         self.max_delay)._replace(
                         core=core._replace(
                             tick=jnp.zeros((), jnp.int32)))
-                pending = int(jnp.sum(
-                    (cstate.ring.ids >= 0)
-                    & (cstate.ring.due >= 0)[..., None]))
+                with trace.span(trace.SYNC, pulls=1):
+                    pending = int(jnp.sum(
+                        (cstate.ring.ids >= 0)
+                        & (cstate.ring.due >= 0)[..., None]))
             totals["replayed"] += extra.get("replayed", 0)
             totals["failures"] += extra.get("failures", 0)
             if extra.get("failures"):
-                n_active = int(jnp.sum(cstate.core.active))
+                with trace.span(trace.SYNC, pulls=1):
+                    n_active = int(jnp.sum(cstate.core.active))
         if self.collect_log:
-            self.log.append({
-                "tick": t, "active": n_active,
-                "sent": int(stats.sent),
-                "accepted": int(stats.accepted),
-                "fetched": int(stats.fetched), "pending": pending,
-                "shard_work": (np.asarray(cstats.shard_fetched)
-                               + np.asarray(cstats.shard_recv)
-                               ).tolist()})
+            # the shard arrays; the ints were pulled above
+            with trace.span(trace.SYNC, pulls=2):
+                self.log.append({
+                    "tick": t, "active": n_active,
+                    "sent": int(stats.sent),
+                    "accepted": int(stats.accepted),
+                    "fetched": int(stats.fetched), "pending": pending,
+                    "shard_work": (np.asarray(cstats.shard_fetched)
+                                   + np.asarray(cstats.shard_recv)
+                                   ).tolist()})
         self._cstate = cstate
         self._n_active = n_active
         self._pending = pending
 
     def _step_plain(self) -> None:
         t, fault_plan, fault_mgr = self._t, self.fault_plan, self.fault_mgr
-        state, stats, send_bufs = self._tick_fn(self._state, self.g)
-        n_active = int(stats.active)
+        with trace.span(trace.DISPATCH):
+            state, stats, send_bufs = self._tick_fn(self._state, self.g)
         totals = self.totals
-        totals["ticks"] += 1
-        totals["sent"] += int(stats.sent)
-        totals["accepted"] += int(stats.accepted)
-        totals["fetched"] += int(stats.fetched)
+        with trace.span(trace.SYNC, pulls=4):
+            n_active = int(stats.active)
+            totals["ticks"] += 1
+            totals["sent"] += int(stats.sent)
+            totals["accepted"] += int(stats.accepted)
+            totals["fetched"] += int(stats.fetched)
         if fault_mgr is not None:
             fault_mgr.record(t, state, send_bufs)
             state, extra = fault_mgr.maybe_fail(t, state, fault_plan)
             totals["replayed"] += extra.get("replayed", 0)
             totals["failures"] += extra.get("failures", 0)
             if extra.get("failures"):
-                n_active = int(jnp.sum(state.active))
-        if self.collect_log:
+                with trace.span(trace.SYNC, pulls=1):
+                    n_active = int(jnp.sum(state.active))
+        if self.collect_log:  # the ints were pulled above
             self.log.append({"tick": t, "active": n_active,
                              "sent": int(stats.sent),
                              "accepted": int(stats.accepted),
@@ -1356,12 +1381,13 @@ class EngineSession:
 
     def step(self) -> None:
         """Run exactly one engine tick (plus its fault bookkeeping)."""
-        if self.schedule == "async":
-            self._step_async()
-        elif self.crowded:
-            self._step_crowded()
-        else:
-            self._step_plain()
+        with trace.span(trace.STEP, tick=self._t):
+            if self.schedule == "async":
+                self._step_async()
+            elif self.crowded:
+                self._step_crowded()
+            else:
+                self._step_plain()
         self._t += 1
 
     def tick_until_quiescent(self, budget: Optional[int] = None) -> dict:

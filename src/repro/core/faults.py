@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import GraphConfig
+from repro.core import trace
 from repro.core.engine import EngineParams, EngineState, init_state
 
 
@@ -175,16 +176,22 @@ class FaultManager:
         # ring log of outgoing buffers: tick -> (send_vals, send_ids) numpy
         self.msg_log: dict[int, tuple] = {}
         self._schedule: Optional[dict[int, list[int]]] = None
+        # what the current kill has pulled to the host: the args of its span
+        self.kill_pulled = {"pulls": 0, "bytes": 0}
 
     # ------------------------------------------------------------------
     def record(self, t: int, state: EngineState, send_bufs,
                clock=None) -> None:
         if t % self.ckpt_every == 0:
-            vals = np.asarray(state.values)
-            act = np.asarray(state.active)
-            cur = np.asarray(state.cursor)
-            aux = (np.asarray(state.aux) if state.aux is not None else None)
-            cl = np.asarray(clock) if clock is not None else None
+            with trace.span(trace.SNAPSHOT, **trace.pulled(
+                    state.values, state.active, state.cursor, state.aux,
+                    clock)):
+                vals = np.asarray(state.values)
+                act = np.asarray(state.active)
+                cur = np.asarray(state.cursor)
+                aux = (np.asarray(state.aux) if state.aux is not None
+                       else None)
+                cl = np.asarray(clock) if clock is not None else None
             for p in range(self.graph.num_shards):
                 self.ckpt[p] = (vals[p].copy(), act[p].copy(), cur[p].copy(),
                                 aux[p].copy() if aux is not None else None)
@@ -193,7 +200,8 @@ class FaultManager:
                     self.ckpt_clock[p] = int(cl[p])
         if self.recovery == "replay":  # checkpoint mode never reads the log
             sv, si = send_bufs
-            self.msg_log[t] = (np.asarray(sv), np.asarray(si))
+            with trace.span(trace.LOG, **trace.pulled(sv, si)):
+                self.msg_log[t] = (np.asarray(sv), np.asarray(si))
             # retention must cover the slack-widened replay window, or
             # crowded runs would always fall to the boundary fallback
             for old in list(self.msg_log):
@@ -243,19 +251,27 @@ class FaultManager:
             self._schedule = plan.schedule(self.graph.num_shards)
         shards = self._schedule.get(t, [])
         extra = {"failures": 0, "replayed": 0}
-        new_clock = None if clock is None else np.asarray(clock).copy()
-        for p in shards:
-            state, replayed = self.fail_shard(t, state, p)
-            extra["failures"] += 1
-            extra["replayed"] += replayed
+        new_clock = None
+        if clock is not None:  # the async step's clock vector, every tick
+            with trace.span(trace.SYNC, pulls=1):
+                new_clock = np.asarray(clock).copy()
+        if not shards:
+            return state, extra
+        with trace.span(trace.KILL) as span:
+            self.kill_pulled = {"pulls": 0, "bytes": 0}
+            for p in shards:
+                state, replayed = self.fail_shard(t, state, p)
+                extra["failures"] += 1
+                extra["replayed"] += replayed
+                if new_clock is not None:
+                    if self.recovery == "checkpoint":
+                        for q in range(self.graph.num_shards):
+                            new_clock[q] = self.ckpt_clock.get(q, 0)
+                    else:
+                        new_clock[p] = self.ckpt_clock.get(p, 0)
             if new_clock is not None:
-                if self.recovery == "checkpoint":
-                    for q in range(self.graph.num_shards):
-                        new_clock[q] = self.ckpt_clock.get(q, 0)
-                else:
-                    new_clock[p] = self.ckpt_clock.get(p, 0)
-        if new_clock is not None and extra["failures"]:
-            extra["clock"] = jnp.asarray(new_clock, jnp.int32)
+                extra["clock"] = jnp.asarray(new_clock, jnp.int32)
+            span.set_metadata(replayed=extra["replayed"], **self.kill_pulled)
         return state, extra
 
     def fail_shard(self, t: int, state: EngineState, p: int
@@ -269,6 +285,7 @@ class FaultManager:
         checkpoint-restore path instead."""
         if self.recovery == "checkpoint":
             return self._global_restore(state), 0
+        self._count_pulls(state.values, state.active, state.cursor)
         values = np.asarray(state.values).copy()
         active = np.asarray(state.active).copy()
         cursor = np.asarray(state.cursor).copy()
@@ -284,6 +301,7 @@ class FaultManager:
             valid = gids < self.graph.num_real_vertices
             v0, a0 = self.prog.init(jnp.asarray(gids, jnp.int32),
                                     jnp.asarray(valid))
+            self._count_pulls(v0, a0)
             values[p], active[p] = np.asarray(v0), np.asarray(a0)
             cursor[p] = 0
             since = -1
@@ -323,6 +341,10 @@ class FaultManager:
         return EngineState(jnp.asarray(values), jnp.asarray(active),
                            jnp.asarray(cursor), state.tick,
                            state.aux), replayed
+
+    def _count_pulls(self, *arrays) -> None:
+        for k, v in trace.pulled(*arrays).items():
+            self.kill_pulled[k] += v
 
     # ------------------------------------------------------------------
     def _global_restore(self, state: EngineState) -> EngineState:

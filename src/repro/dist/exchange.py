@@ -180,6 +180,7 @@ def make_wire_codec(num_shards: int, capacity: int, vs: int,
 # ======================================================================
 # Transports
 # ======================================================================
+@jax.named_scope("tick.exchange")
 def exchange_local(codec: WireCodec, send_vals: jnp.ndarray,
                    send_ids: jnp.ndarray
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -197,6 +198,7 @@ def exchange_local(codec: WireCodec, send_vals: jnp.ndarray,
     return codec.decode(rv, rs), codec.decode_ids(ri)
 
 
+@jax.named_scope("tick.exchange")
 def exchange_dist(codec: WireCodec, send_vals: jnp.ndarray,
                   send_ids: jnp.ndarray, axis_name: str
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -277,6 +279,7 @@ def _ring_push_pop(ring: DelayRing, send_vals, send_ids, tick, delays,
     return dv, di, DelayRing(vals, ids, due), pending
 
 
+@jax.named_scope("tick.exchange")
 def exchange_local_delayed(codec: WireCodec, ring: DelayRing,
                            send_vals: jnp.ndarray, send_ids: jnp.ndarray,
                            tick, delays, identity, recv_gate=None
@@ -302,6 +305,7 @@ def exchange_local_delayed(codec: WireCodec, ring: DelayRing,
     return rv, ri, ring, pending
 
 
+@jax.named_scope("tick.exchange")
 def exchange_dist_delayed(codec: WireCodec, ring: DelayRing,
                           send_vals: jnp.ndarray, send_ids: jnp.ndarray,
                           tick, delays_row, axis_name: str, identity,

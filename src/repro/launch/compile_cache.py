@@ -19,7 +19,13 @@ def use_compile_cache() -> str:
 
     ``JAX_COMPILATION_CACHE_DIR``, when set, is the place: JAX reads it
     itself and nothing here overrides it.  Otherwise the cache lives in the
-    git-ignored ``.jax_cache`` directory of the checkout."""
+    git-ignored ``.jax_cache`` directory of the checkout.
+
+    The cache key covers each op's metadata: by default JAX strips it, and
+    a program that differs from a cached one only in its ``op_name``s (the
+    engine's ``tick.*`` scopes) would run the cached executable, whose ops
+    a device trace then reports under the other program's names."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -128,6 +128,18 @@ class TestJitPurity:
                 return jax.jit(tick)
         """) == set()
 
+    def test_engine_span_inside_jit_fires(self):
+        assert rules_hit("""
+            import jax
+            from repro.core import trace
+
+            def make_tick():
+                def tick(x):
+                    with trace.span(trace.STEP):
+                        return x + 1
+                return jax.jit(tick)
+        """) == {"jit-purity"}
+
     def test_suppressed_inline(self):
         res = run(JIT_NP.replace(
             "return np.sum(x)",

@@ -54,8 +54,9 @@ def test_compile_cache_follows_env(monkeypatch):
                         lambda *a: calls.append(a))
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
     assert compile_cache.use_compile_cache() == "/elsewhere"
-    assert calls == []
+    keyed = ("jax_compilation_cache_include_metadata_in_key", True)
+    assert calls == [keyed]  # the directory is left to JAX
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     path = compile_cache.use_compile_cache()
     assert path == os.path.join(ROOT, ".jax_cache")
-    assert calls == [("jax_compilation_cache_dir", path)]
+    assert calls == [keyed, keyed, ("jax_compilation_cache_dir", path)]

@@ -5,6 +5,7 @@ Pallas kernel, the tick's device-memory footprint, and the collectives of
 the four-chip shard_map tick.  Nothing here runs; every test compiles.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +16,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 from repro.configs import get_graph_config
 from repro.core import engine as E
 from repro.core import programs as PR
+from repro.core import trace
 from repro.dist.sharding import vertex_partition
 from repro.kernels.semiring_spmv import EDGE_BLOCK, SEMIRINGS, spmv_partials
 
@@ -50,7 +52,9 @@ def test_spmv_compiles_for_v5e(one_chip, semiring):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_local_tick_fits_one_v5e(one_chip):
+@pytest.fixture(scope="module")
+def local_tick(one_chip):
+    """``asymp_cc``'s local tick, compiled for one v5e."""
     cfg = get_graph_config("asymp_cc")
     prog = PR.get_program(cfg)
     P_ = cfg.num_shards
@@ -69,10 +73,23 @@ def test_local_tick_fits_one_v5e(one_chip):
     g = E.ShardGraph(spec((P_, vs + 1), jnp.int32),
                      spec((P_, es), jnp.int32), None)
     tick = E.make_local_tick(prog, ep, prog.weighted)
-    mem = tick.lower(state, g).compile().memory_analysis()
+    return tick.lower(state, g).compile()
+
+
+def test_local_tick_fits_one_v5e(local_tick):
+    mem = local_tick.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < HBM_BYTES, total
+
+
+def test_tick_phases_keep_their_scopes_on_v5e(local_tick):
+    """The TPU compiler keeps each phase's ``jax.named_scope`` in the
+    ``op_name`` metadata that a device trace reports per op."""
+    paths = re.findall(r'op_name="([^"]*)"', local_tick.as_text())
+    for scope in trace.SCOPES:  # vmapped phases read ``vmap(tick.select)``
+        named = re.compile(rf"(^|[/(]){re.escape(scope)}([/)]|$)")
+        assert any(named.search(p) for p in paths), scope
 
 
 def test_mesh_tick_exchanges_all_to_all(topo):

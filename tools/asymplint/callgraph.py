@@ -27,6 +27,9 @@ TRACING_WRAPPERS = frozenset({"jit", "pallas_call", "shard_map", "pmap"})
 # host at trace time and constant-fold into the compiled program.
 BANNED_MODULES = frozenset({"numpy", "random", "time", "os", "io",
                             "secrets", "datetime"})
+# The engine's host spans: a span opened at trace time times the trace,
+# not the run.
+HOST_ONLY = frozenset({"repro.core.trace"})
 BANNED_BUILTINS = frozenset({"print", "open", "input", "breakpoint"})
 
 _FUNC = (ast.FunctionDef, ast.AsyncFunctionDef)
@@ -221,7 +224,8 @@ class ModuleGraph:
                 root = root.value
             if isinstance(root, ast.Name):
                 origin = self.aliases.get(root.id, "")
-                if origin.split(".")[0] in BANNED_MODULES:
+                if (origin.split(".")[0] in BANNED_MODULES
+                        or origin in HOST_ONLY):
                     yield (node.lineno,
                            f"`{root.id}.{node.attr}` resolves to host "
                            f"module `{origin}`")
