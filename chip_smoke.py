@@ -2,7 +2,7 @@
 answer against an independent oracle.
 
     python chip_smoke.py            # one chip: mining, serving, kernel
-    python chip_smoke.py --chips 4  # four chips: shard_map tick vs one chip
+    python chip_smoke.py --chips 4  # four chips: mesh session vs one chip
 
 One process holds the chip for the whole run; phases run in order and any
 failed check, unconverged run or exception exits non-zero.  Each phase
@@ -15,7 +15,6 @@ whose JAX finds no TPU the script exits non-zero before any phase.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 import shutil
@@ -28,14 +27,12 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import (AxisType, Mesh, NamedSharding,  # noqa: E402
-                          PartitionSpec as P)
+from jax.sharding import AxisType, Mesh  # noqa: E402
 
 from repro.configs import get_graph_config  # noqa: E402
 from repro.core import engine as E  # noqa: E402
 from repro.core import graph as G  # noqa: E402
 from repro.core import merger  # noqa: E402
-from repro.core import programs as PR  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.kernels.semiring_spmv import EDGE_BLOCK, spmv_partials  # noqa: E402
 from repro.launch import graph_mine, graph_serve  # noqa: E402
@@ -187,62 +184,49 @@ def phase_kernel(reduced: bool = False) -> dict:
             "plus_times_rel_l1": rel_l1, "compiled_kernel": compiled}
 
 
-def _run_ticks(tick, state, g, max_ticks: int):
-    ticks = 0
-    while ticks < max_ticks:
-        state, stats = tick(state, g)[:2]
-        ticks += 1
-        if int(stats.active) == 0:
-            return state, ticks
-    raise SystemExit(f"chip_smoke: FAILED: no fixpoint in {max_ticks} ticks")
-
-
 def phase_dist_vs_local(devices, reduced: bool = False) -> dict:
-    """The shard_map tick over a ``workers`` mesh of ``devices`` against
-    the vmapped local tick on one device, on MINE_CONFIG with one shard
-    per device: bitwise-equal fixpoints, both equal to union-find."""
-    cfg = dataclasses.replace(_config(MINE_CONFIG, reduced),
-                              num_shards=len(devices))
+    """``EngineSession`` on a ``workers`` mesh of ``devices`` (its shards
+    split over them, the tick under ``shard_map``) against the session on
+    one device, on MINE_CONFIG: bitwise-equal fixpoints after the same
+    ticks, both equal to union-find."""
+    cfg = _config(MINE_CONFIG, reduced)
     t0 = time.time()
     graph = G.build_sharded_graph(cfg)
     build_s = time.time() - t0
-    prog = PR.get_program(cfg)
-    ep = E.default_params(cfg, graph, prog)
 
     mesh = Mesh(np.asarray(devices), ("workers",),
                 axis_types=(AxisType.Auto,))
-    rows, whole = NamedSharding(mesh, P("workers")), NamedSharding(mesh, P())
-    state = jax.jit(lambda: E.init_state(prog, graph),
-                    out_shardings=E.EngineState(rows, rows, rows, whole,
-                                                None))()
-    g = E.ShardGraph(
-        jax.device_put(graph.row_ptr.astype(np.int32), rows),
-        jax.device_put(np.where(graph.col_idx < 0, -1, graph.col_idx
-                                ).astype(np.int32), rows), None)
-    dist_tick = jax.jit(E.make_dist_tick(prog, ep, mesh, prog.weighted))
+    dist = E.EngineSession(cfg, graph=graph, mesh=mesh)
     if len(devices) > 1:  # a one-device axis lowers to no collective
-        need("all_to_all" in dist_tick.lower(state, g).as_text(),
-             "the dist tick has no all_to_all")
+        need("all_to_all" in dist._tick_fn.lower(dist.state,
+                                                 dist.g).as_text(),
+             "the mesh session's tick has no all_to_all")
     t0 = time.time()
-    dist, dist_ticks = _run_ticks(dist_tick, state, g, cfg.max_ticks)
+    dist_totals = dist.tick_until_quiescent()
     dist_s = time.time() - t0
 
-    local_tick = E.make_local_tick(prog, ep, prog.weighted)
     with jax.default_device(devices[0]):
-        state0, g0 = E.init_state(prog, graph), E.to_device_graph(graph)
-    t0 = time.time()
-    local, local_ticks = _run_ticks(local_tick, state0, g0, cfg.max_ticks)
+        local = E.EngineSession(cfg, graph=graph)
+        t0 = time.time()
+        local_totals = local.tick_until_quiescent()
     local_s = time.time() - t0
 
-    dist_values = np.asarray(dist.values)
-    need(np.array_equal(dist_values, np.asarray(local.values)),
-         "dist-tick fixpoint differs from the local tick's")
+    need(dist_totals["converged"] and local_totals["converged"],
+         "a session did not reach quiescence")
+    dist_values = np.asarray(dist.state.values)
+    need(np.array_equal(dist_values, np.asarray(local.state.values)),
+         "the mesh session's fixpoint differs from the one-device one's")
+    need(dist_totals["ticks"] == local_totals["ticks"]
+         and dist_totals["sent"] == local_totals["sent"],
+         "the mesh session ran other ticks than the one-device one")
     oracle = G.cc_oracle(cfg.num_vertices, G.generate_edges(cfg))
-    need(np.array_equal(dist_values.reshape(-1)[:cfg.num_vertices], oracle),
-         "dist-tick labels differ from cc_oracle")
+    need(np.array_equal(merger.extract(dist.state, graph, dist.prog),
+                        oracle),
+         "the mesh session's labels differ from cc_oracle")
     return {"build_s": build_s, "workers": len(devices),
-            "dist_ticks": dist_ticks, "dist_s": dist_s,
-            "local_ticks": local_ticks, "local_s": local_s}
+            "shards": cfg.num_shards,
+            "dist_ticks": dist_totals["ticks"], "dist_s": dist_s,
+            "local_ticks": local_totals["ticks"], "local_s": local_s}
 
 
 # ======================================================================
@@ -254,7 +238,7 @@ def _peak_bytes(devices) -> list:
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: run only the four-chip shard_map phase")
+                    help="4: run only the four-chip mesh-session phase")
     args = ap.parse_args(argv)
     use_compile_cache()
 

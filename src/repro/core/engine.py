@@ -14,11 +14,14 @@ One tick per shard (Fig 1 / Fig 2 mapped to SPMD):
                CC/SSSP/BFS, max for widest-path/labelprop, or for
                reachability); improved vertices join the frontier
 
-Two execution modes sharing the same per-shard code:
-  local  — arrays [P, ...] on one device, vmap + transpose as the exchange
-           (tests, benchmarks, fault-injection studies)
-  dist   — shard_map over a 1-D `workers` mesh with lax.all_to_all
-           (the production path; dry-run lowers it on 256/512 chips)
+One tick builder, two placements of the same per-shard code:
+  one device — arrays [P, ...], vmap + transpose as the exchange
+  a mesh     — shard_map over a 1-D `workers` mesh (:func:`shard_local_tick`):
+               each device vmaps its block of P / devices shards, and one
+               lax.all_to_all carries the exchange across devices
+               (EngineSession(mesh=...); the dry-run lowers it on 256/512
+               chips)
+The crowded and async schedules keep shard_map builders of their own.
 """
 from __future__ import annotations
 
@@ -85,6 +88,11 @@ class EngineParams:
     # settled work drains first and soon-to-be-improved values are not
     # propagated redundantly (0 = off; only the crowded tick uses it)
     straggler_demote: int = 0
+    # the mesh the tick runs on (:func:`shard_local_tick`): the name of its
+    # one axis and its number of devices, each running num_shards /
+    # mesh_devices shards.  None: every shard in one device's arrays
+    mesh_axis: Optional[str] = None
+    mesh_devices: int = 1
 
 
 def wire_codec(prog, ep: EngineParams) -> ex_mod.WireCodec:
@@ -98,7 +106,7 @@ def wire_codec(prog, ep: EngineParams) -> ex_mod.WireCodec:
         requested=ep.wire_compression, value_kind=prog.dtype,
         identity=prog.identity, max_int_value=ep.wire_value_bound,
         quantize_direction=prog.aggregator.quantize_direction,
-        idempotent=prog.aggregator.idempotent)
+        idempotent=prog.aggregator.idempotent, axis_name=ep.mesh_axis)
 
 
 def derive_params(cfg: GraphConfig, *, num_shards: int, vs: int, es: int,
@@ -422,14 +430,24 @@ def _phase2_receive_push(prog, ep: EngineParams, residual, active,
 
 
 # ======================================================================
-# Local (single-device, vmapped) execution
+# The plain tick: on one device (vmap), or on a mesh (shard_map)
 # ======================================================================
 def make_local_tick(prog, ep: EngineParams, weighted: bool):
+    """The plain tick over a block of shards ``[P, ...]``.  With
+    ``ep.mesh_axis`` it is one device's body under ``shard_map``
+    (:func:`shard_local_tick`): the block is the device's ``num_shards /
+    mesh_devices`` shards, the exchange crosses devices, and the counts
+    are summed over the mesh."""
     codec = wire_codec(prog, ep)
     push_mode = not prog.aggregator.idempotent
 
     def tick(state: EngineState, g: ShardGraph):
-        shard_ids = jnp.arange(ep.num_shards)
+        if ep.mesh_axis is None:
+            shard_ids = jnp.arange(ep.num_shards)
+        else:
+            Pd = ep.num_shards // ep.mesh_devices
+            shard_ids = (jax.lax.axis_index(ep.mesh_axis) * Pd
+                         + jnp.arange(Pd))
         w = g.weights if weighted else None
         aux = state.aux if push_mode else None
 
@@ -458,10 +476,51 @@ def make_local_tick(prog, ep: EngineParams, weighted: bool):
             aux = state.aux  # None (or an untouched caller-supplied plane)
         stats = TickStats(jnp.sum(active), jnp.sum(sent), jnp.sum(accepted),
                           jnp.sum(fetched))
+        if ep.mesh_axis is not None:
+            stats = jax.lax.psum(stats, ep.mesh_axis)
         return (EngineState(values, active, cursor, state.tick + 1, aux),
                 stats, (sv, si))
 
     return jax.jit(tick)
+
+
+def on_mesh(ep: EngineParams, mesh: Mesh) -> EngineParams:
+    """``ep`` for the tick's body on a 1-D ``mesh``; ``ValueError`` where
+    the mesh cannot hold the shards in equal blocks."""
+    if len(mesh.axis_names) != 1:
+        raise ValueError(f"the engine runs on a 1-D mesh; this one has axes "
+                         f"{mesh.axis_names}")
+    n = mesh.devices.size
+    if ep.num_shards % n:
+        raise ValueError(f"{ep.num_shards} shards do not split evenly over "
+                         f"the mesh's {n} devices")
+    return dataclasses.replace(ep, mesh_axis=mesh.axis_names[0],
+                               mesh_devices=n)
+
+
+def shard_local_tick(prog, ep: EngineParams, mesh: Mesh, weighted: bool):
+    """The plain tick over ``mesh``: :func:`make_local_tick` (looked up
+    when the tick is built) as each device's body under ``shard_map``.
+    Takes and returns what the one-device tick does, the shard axis of
+    every array split over the mesh and the tick counter replicated; its
+    ``TickStats`` are the mesh's totals.  Not jitted, so that a caller may
+    donate the state."""
+    ep = on_mesh(ep, mesh)
+    body = make_local_tick(prog, ep, weighted)
+    rows = P(ep.mesh_axis)
+
+    def specs(tree):
+        return jax.tree.map(lambda x: P() if jnp.ndim(x) == 0 else rows,
+                            tree)
+
+    def tick(state: EngineState, g: ShardGraph):
+        return shard_map(
+            body, mesh=mesh, in_specs=(specs(state), specs(g)),
+            out_specs=(specs(state), TickStats(P(), P(), P(), P()),
+                       (rows, rows)),
+            check_vma=False)(state, g)
+
+    return tick
 
 
 # ======================================================================
@@ -585,58 +644,8 @@ def make_crowded_tick(prog, ep: EngineParams, weighted: bool):
 
 
 # ======================================================================
-# Distributed (shard_map over `workers`) execution
+# Crowded ticks over a `workers` mesh (shard_map, one shard per device)
 # ======================================================================
-def make_dist_tick(prog, ep: EngineParams, mesh: Mesh, weighted: bool):
-    axis = "workers"
-    codec = wire_codec(prog, ep)
-    push_mode = not prog.aggregator.idempotent
-
-    def local_fn(values, active, cursor, tick, aux, row_ptr, col_idx,
-                 weights):
-        sid = jax.lax.axis_index(axis)
-        values, active, cursor = values[0], active[0], cursor[0]
-        aux_row = aux[0] if push_mode else None
-        w = weights[0] if weighted else None
-        active, cursor, sv, si, sent, fetched, values, aux_row = \
-            _phase1_create(prog, ep, values, active, cursor, row_ptr[0],
-                           col_idx[0], w, sid, aux=aux_row)
-        rv, ri = ex_mod.exchange_dist(codec, sv, si, axis)
-        if push_mode:
-            residual, active, accepted = _phase2_receive_push(
-                prog, ep, aux_row[0], active, rv, ri)
-            aux_out = aux_row.at[0].set(residual)[None]
-        else:
-            values, active, cursor, accepted = _phase2_receive(
-                prog, ep, values, active, cursor, rv, ri)
-            aux_out = aux  # the replicated dummy scalar
-        n_active = jax.lax.psum(jnp.sum(active), axis)
-        sent = jax.lax.psum(sent, axis)
-        accepted = jax.lax.psum(accepted, axis)
-        fetched = jax.lax.psum(fetched, axis)
-        return (values[None], active[None], cursor[None], tick + 1,
-                aux_out, TickStats(n_active, sent, accepted, fetched))
-
-    def tick_fn(state: EngineState, g: ShardGraph):
-        aux_spec = P(axis) if push_mode else P()
-        sm = shard_map(
-            local_fn, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P(), aux_spec, P(axis),
-                      P(axis), P(axis) if weighted else P()),
-            out_specs=(P(axis), P(axis), P(axis), P(), aux_spec,
-                       TickStats(P(), P(), P(), P())),
-            check_vma=False)
-        weights = g.weights if weighted else jnp.zeros((), jnp.float32)
-        aux_in = state.aux if push_mode else jnp.zeros((), jnp.float32)
-        values, active, cursor, tick, aux, stats = sm(
-            state.values, state.active, state.cursor, state.tick, aux_in,
-            g.row_ptr, g.col_idx, weights)
-        return EngineState(values, active, cursor, tick,
-                           aux if push_mode else state.aux), stats
-
-    return tick_fn
-
-
 def init_crowded_dist_state(prog, ep: EngineParams, graph: ShardedGraph,
                             max_delay: int) -> CrowdedState:
     """Like :func:`init_crowded_state` but with the per-shard (sender-side)
@@ -1045,13 +1054,20 @@ class EngineSession:
     ``cfg.schedule``.  Async runs always cross the delay ring and are
     quiescent when EVERY shard's frontier is empty AND its inbound ring
     rows are drained.
+
+    ``mesh`` — a 1-D device mesh (``launch/mesh.py`` ``make_worker_mesh``)
+    runs the plain tick over it (:func:`shard_local_tick`): state and
+    graph split over the mesh by shard, ``num_shards / devices`` shards
+    on each device.  Fault plans, latency models and the async schedule
+    do not run on a mesh yet (``ValueError``).
     """
 
     def __init__(self, cfg: GraphConfig, *,
                  graph: Optional[ShardedGraph] = None, prog=None,
                  params: Optional[EngineParams] = None,
                  collect_log: bool = False, fault_plan=None, latency=None,
-                 schedule: Optional[str] = None):
+                 schedule: Optional[str] = None,
+                 mesh: Optional[Mesh] = None):
         from repro.core import faults as faults_mod
         from repro.dist import latency as lat_mod
         self._faults = faults_mod
@@ -1059,7 +1075,10 @@ class EngineSession:
         self.graph = graph or build_sharded_graph(cfg)
         self.prog = prog or prog_mod.get_program(cfg)
         self.ep = params or default_params(cfg, self.graph, self.prog)
-        self.g = to_device_graph(self.graph)
+        self.mesh = mesh
+        if mesh is not None:
+            on_mesh(self.ep, mesh)  # the shards split evenly over it
+        self.g = self._place(to_device_graph(self.graph))
         self.collect_log = collect_log
         self.fault_plan = fault_plan
 
@@ -1076,6 +1095,14 @@ class EngineSession:
                         or faults_mod.injects_slowdown(fault_plan))
         self.max_delay = (max(latency.max_delay if latency else 0, injected)
                           if self.crowded else 0)
+        if mesh is not None:
+            unsupported = [what for what, used in (
+                ("a fault plan", fault_plan is not None),
+                ("a latency model", self.crowded),
+                ("the async schedule", schedule == "async")) if used]
+            if unsupported:
+                raise ValueError(f"{' and '.join(unsupported)} cannot run "
+                                 "on a mesh yet: run without mesh=")
 
         self.log: list = []
         self.totals = {"ticks": 0, "sent": 0, "accepted": 0, "fetched": 0,
@@ -1164,12 +1191,26 @@ class EngineSession:
     def _init_plain(self) -> None:
         self._init_sync_fault_mgr()
         self.ep_run = self.ep
-        self._tick_fn = make_local_tick(self.prog, self.ep,
-                                        self.prog.weighted)
+        if self.mesh is None:
+            self._tick_fn = make_local_tick(self.prog, self.ep,
+                                            self.prog.weighted)
+        else:
+            self._tick_fn = jax.jit(shard_local_tick(
+                self.prog, self.ep, self.mesh, self.prog.weighted))
         # a zero-budget run (or an initially empty frontier) must still
         # report a well-defined activity count
-        self._state = init_state(self.prog, self.graph)
+        self._state = self._place(init_state(self.prog, self.graph))
         self._n_active = int(jnp.sum(self._state.active))
+
+    def _place(self, tree):
+        """``tree`` split over the session's mesh by its leading (shard)
+        axis, scalars replicated; as it is without a mesh."""
+        if self.mesh is None:
+            return tree
+        rows = NamedSharding(self.mesh, P(self.mesh.axis_names[0]))
+        whole = NamedSharding(self.mesh, P())
+        return jax.tree.map(lambda x: jax.device_put(
+            x, whole if jnp.ndim(x) == 0 else rows), tree)
 
     # -- per-tick drivers (bookkeeping order mirrors across all three:
     # totals, fault handling, log entry — keep changes in sync) --------
@@ -1456,7 +1497,7 @@ class EngineSession:
         new = EngineSession(self.cfg, graph=self.graph, prog=self.prog,
                             params=self.ep, collect_log=self.collect_log,
                             fault_plan=self.fault_plan, latency=self.latency,
-                            schedule=self.schedule)
+                            schedule=self.schedule, mesh=self.mesh)
         new._tick_fn = self._tick_fn
         if self.schedule == "async":
             new._astate = self._astate
@@ -1490,15 +1531,16 @@ class EngineSession:
         elif self.crowded:
             self._cstate = self._cstate._replace(core=core)
         else:
-            self._state = core
+            self._state = self._place(core)
 
     def rebind_graph(self, graph: ShardedGraph) -> None:
         """Point the session at a patched graph (streaming delta).  The
         jitted tick retraces automatically if the padded edge width
         changed; EngineParams stay as derived for the original graph, so
-        route capacity keeps its head-room across small deltas."""
+        route capacity keeps its head-room across small deltas.  On a
+        mesh the graph is split over it as the first one was."""
         self.graph = graph
-        self.g = to_device_graph(graph)
+        self.g = self._place(to_device_graph(graph))
         if self.fault_mgr is not None:
             self.fault_mgr.graph = graph
 
@@ -1531,7 +1573,8 @@ def run_to_convergence(cfg: GraphConfig, *, graph: Optional[ShardedGraph] = None
                        max_ticks: Optional[int] = None,
                        collect_log: bool = False,
                        fault_plan=None, latency=None,
-                       schedule: Optional[str] = None):
+                       schedule: Optional[str] = None,
+                       mesh: Optional[Mesh] = None):
     """Host loop (the propagation phase). Returns (state, metrics dict).
 
     Thin wrapper over :class:`EngineSession` — construct a session, tick
@@ -1541,7 +1584,7 @@ def run_to_convergence(cfg: GraphConfig, *, graph: Optional[ShardedGraph] = None
     """
     session = EngineSession(cfg, graph=graph, prog=prog, params=params,
                             collect_log=collect_log, fault_plan=fault_plan,
-                            latency=latency, schedule=schedule)
+                            latency=latency, schedule=schedule, mesh=mesh)
     totals = session.tick_until_quiescent(
         cfg.max_ticks if max_ticks is None else max_ticks)
     return session.state, totals
@@ -1665,6 +1708,6 @@ def lower_tick_for_mesh(cfg: GraphConfig, mesh_2d, n_workers: int):
         info["ring_slots"] = L1
         info["latency_profile"] = cfg.latency_profile
         return compiled, info
-    tick_fn = make_dist_tick(prog, ep, mesh, prog.weighted)
+    tick_fn = shard_local_tick(prog, ep, mesh, prog.weighted)
     compiled = jax.jit(tick_fn, donate_argnums=(0,)).lower(state, g).compile()
     return compiled, info

@@ -6,7 +6,9 @@ device trace's clock; their keyword args are counts the host already holds.
 Open them in host code only, never inside a jitted function.  Device scopes
 (``tick.*``) are ``jax.named_scope`` names, carried in each op's ``op_name``
 metadata; ``dist/exchange.py`` spells ``tick.exchange`` itself, since
-``repro.dist`` imports nothing from above it.
+``repro.dist`` imports nothing from above it, and, on a mesh,
+``tick.wire`` inside it: the cross-device ``all_to_all``.  ``SCOPES`` are
+the phases every tick has.
 """
 import jax
 

@@ -4,12 +4,15 @@ The ASYMP engine produces, per shard, a pair of send buffers
 ``(values [Pn, cap], ids [Pn, cap])`` — row ``q`` holds the messages bound
 for shard ``q``, ``ids`` are destination-local vertex slots (-1 = empty).
 Delivery is a shard transpose: receiver ``q`` ends with row ``p`` from
-every sender ``p``.  Two transports implement it:
+every sender ``p``.  :func:`exchange_local` implements it over a block of
+shards ``[P, Pn, cap]``:
 
-  * **local**  — all shards live in one device array ``[P, Pn, cap]``;
-    the transpose is ``swapaxes(0, 1)`` (tests, benchmarks, fault studies);
-  * **dist**   — one shard per device under ``shard_map``; the transpose
-    is ``lax.all_to_all`` over the ``workers`` mesh axis (production).
+  * with no mesh axis on the codec, the block is every shard and the
+    transpose is ``swapaxes(0, 1)``;
+  * with ``codec.axis_name`` (inside ``shard_map``), the block is one
+    device's shards: one ``lax.all_to_all`` over the axis (the scope
+    ``tick.wire``) first regroups the blocks by destination device, then
+    the same transpose runs on what arrived.
 
 Both run the *same* wire codec so their results are bit-identical:
 
@@ -117,6 +120,8 @@ class WireCodec:
     # min-monotone values from under-estimating, "down" keeps max-monotone
     # values from over-estimating (never cross the fixpoint)
     quantize_direction: str = "up"
+    # the mesh axis the blocks cross (None: every shard on one device)
+    axis_name: Optional[str] = None
 
     @property
     def bits(self) -> int:
@@ -166,7 +171,8 @@ def make_wire_codec(num_shards: int, capacity: int, vs: int,
                     requested: str, value_kind: str, identity,
                     max_int_value: int = 0,
                     quantize_direction: str = "up",
-                    idempotent: bool = True) -> WireCodec:
+                    idempotent: bool = True,
+                    axis_name: Optional[str] = None) -> WireCodec:
     mode = effective_compression(requested, value_kind, max_int_value,
                                  idempotent)
     return WireCodec(
@@ -174,12 +180,23 @@ def make_wire_codec(num_shards: int, capacity: int, vs: int,
         value_kind=value_kind, identity=float(identity)
         if value_kind == "float32" else int(identity),
         compress_ids=(mode != "none" and vs <= _INT_SENTINEL[16] - 1),
-        quantize_direction=quantize_direction)
+        quantize_direction=quantize_direction, axis_name=axis_name)
 
 
 # ======================================================================
 # Transports
 # ======================================================================
+@jax.named_scope("tick.wire")
+def _across_devices(axis_name: str, *blocks):
+    """One device's ``[Pd, Pn, ...]`` blocks (its senders, every
+    destination shard) -> ``[Pn, Pd, ...]`` (every sender, this device's
+    destinations): the one cross-device step, an ``all_to_all`` over
+    ``axis_name``."""
+    return [None if b is None
+            else jax.lax.all_to_all(b, axis_name, 1, 0, tiled=True)
+            for b in blocks]
+
+
 @jax.named_scope("tick.exchange")
 def exchange_local(codec: WireCodec, send_vals: jnp.ndarray,
                    send_ids: jnp.ndarray
@@ -189,27 +206,18 @@ def exchange_local(codec: WireCodec, send_vals: jnp.ndarray,
     The encode/decode round-trip runs even though no wire is crossed, so
     local and distributed executions of the same codec are bit-identical
     (this is what lets single-device tests certify the production path).
+    With ``codec.axis_name`` (inside ``shard_map``) the ``P`` senders are
+    one device's block of ``Pd`` shards, and the result is ``[Pd, Pn,
+    cap]``: each of the device's shards hears every sender of the mesh.
     """
     enc_v, scales = codec.encode(send_vals)
     enc_i = codec.encode_ids(send_ids)
+    if codec.axis_name is not None:
+        enc_v, enc_i, scales = _across_devices(codec.axis_name, enc_v,
+                                               enc_i, scales)
     rv = jnp.swapaxes(enc_v, 0, 1)
     ri = jnp.swapaxes(enc_i, 0, 1)
     rs = jnp.swapaxes(scales, 0, 1) if scales is not None else None
-    return codec.decode(rv, rs), codec.decode_ids(ri)
-
-
-@jax.named_scope("tick.exchange")
-def exchange_dist(codec: WireCodec, send_vals: jnp.ndarray,
-                  send_ids: jnp.ndarray, axis_name: str
-                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-shard [Pn, cap] send buffers -> [Pn, cap] receive buffers via
-    ``all_to_all`` over ``axis_name`` (row q of the result is sender q's
-    buffer for this shard).  Must run inside ``shard_map``."""
-    a2a = lambda x: jax.lax.all_to_all(x, axis_name, 0, 0, tiled=True)
-    enc_v, scales = codec.encode(send_vals)
-    rv = a2a(enc_v)
-    ri = a2a(codec.encode_ids(send_ids))
-    rs = a2a(scales) if scales is not None else None
     return codec.decode(rv, rs), codec.decode_ids(ri)
 
 

@@ -12,6 +12,8 @@ per-tick metrics log.
                                     # idempotent SUM aggregation)
   python -m repro.launch.graph_mine --config asymp_cc --slowdown 0.5 \
       --latency-profile stragglers      # crowded-cluster emulation (§5.4)
+  python -m repro.launch.graph_mine --config asymp_cc --chips 4
+                                    # the shards split over four devices
 """
 from __future__ import annotations
 
@@ -31,6 +33,7 @@ from repro.core import programs as PR
 from repro.core.faults import FaultPlan
 from repro.dist import latency as lat_mod
 from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_worker_mesh
 
 
 class MineRun(NamedTuple):
@@ -72,6 +75,9 @@ def main(argv: Optional[Sequence[str]] = None) -> MineRun:
                          "per-shard progress (seeded interleaving)")
     ap.add_argument("--async-seed", type=int, default=None,
                     help="seed for the async interleaving (determinism)")
+    ap.add_argument("--chips", type=int, default=1,
+                    help="devices to split the shards over (a `workers` "
+                         "mesh; 1 = every shard on one device)")
     ap.add_argument("--reduced", action="store_true",
                     help="run the config's tiny .reduced() variant "
                          "(CI smoke)")
@@ -117,7 +123,7 @@ def main(argv: Optional[Sequence[str]] = None) -> MineRun:
           f"{', weighted' if prog.weighted else ''}) "
           f"V={cfg.num_vertices} E~{cfg.num_edges} shards={cfg.num_shards} "
           f"priority={cfg.priority}@{cfg.enforce_fraction} "
-          f"schedule={cfg.schedule}")
+          f"schedule={cfg.schedule} chips={args.chips}")
     t0 = time.time()
     graph = G.build_sharded_graph(cfg)
     build_s = time.time() - t0
@@ -131,8 +137,10 @@ def main(argv: Optional[Sequence[str]] = None) -> MineRun:
               f"{lat_mod.from_config(cfg).describe()} "
               f"(straggler_demote={cfg.straggler_demote})")
     t0 = time.time()
+    mesh = make_worker_mesh(args.chips) if args.chips > 1 else None
     state, totals = E.run_to_convergence(cfg, graph=graph, prog=prog,
-                                         fault_plan=plan, collect_log=True)
+                                         fault_plan=plan, collect_log=True,
+                                         mesh=mesh)
     wall = time.time() - t0
     print(f"[graph_mine] propagation: {totals['ticks']} ticks, "
           f"{totals['sent']} messages, {totals['failures']} failures, "

@@ -31,7 +31,11 @@ def make_local_mesh(model: int = 1) -> Mesh:
 
 
 def make_worker_mesh(num_workers: int | None = None) -> Mesh:
-    """1-D mesh for the ASYMP graph engine (the `workers` axis)."""
+    """1-D mesh for the ASYMP graph engine (the `workers` axis) over the
+    first ``num_workers`` devices (all of them by default)."""
     devs = np.array(jax.devices())
     n = num_workers or devs.size
+    if n > devs.size:
+        raise ValueError(f"a mesh of {n} workers, but JAX found "
+                         f"{devs.size} device(s)")
     return Mesh(devs[:n], ("workers",))

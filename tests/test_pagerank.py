@@ -272,7 +272,7 @@ class TestWireGate:
 # ======================================================================
 class TestDistTick:
     def test_dist_matches_local_including_aux(self):
-        """The shard_map tick threads the aux planes; on a 1-worker mesh
+        """The tick on a mesh threads the aux planes; on a 1-worker mesh
         it must track the local tick bit-for-bit."""
         cfg = _cfg(num_vertices=128, avg_degree=4, num_shards=1,
                    enforce_fraction=1.0)
@@ -282,12 +282,13 @@ class TestDistTick:
         dg = E.to_device_graph(g)
         mesh = Mesh(np.array(jax.devices()[:1]), ("workers",))
         tick_l = E.make_local_tick(prog, ep, prog.weighted)
-        tick_d = jax.jit(E.make_dist_tick(prog, ep, mesh, prog.weighted))
+        tick_d = jax.jit(E.shard_local_tick(prog, ep, mesh, prog.weighted))
         sl = E.init_state(prog, g)
         sd = E.init_state(prog, g)
         for t in range(120):
             sl, stats, _ = tick_l(sl, dg)
-            sd, _ = tick_d(sd, dg)
+            sd, stats_d, _ = tick_d(sd, dg)
+            assert stats_d == stats
             if t % 20 == 0 or t == 119:
                 np.testing.assert_array_equal(np.asarray(sl.values),
                                               np.asarray(sd.values))

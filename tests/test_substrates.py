@@ -299,7 +299,9 @@ class TestExchange:
             assert float(jnp.max(err - scale / qmax)) <= 1e-5, mode
 
     def test_local_and_dist_transports_agree(self):
-        """Same codec, both transports, bit-identical delivery."""
+        """Same codec, one device or a mesh (the cross-device
+        ``all_to_all`` step), bit-identical delivery."""
+        import dataclasses
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.dist import exchange as X
         from jax import shard_map
@@ -313,11 +315,14 @@ class TestExchange:
             jnp.asarray([1, 2, 3]))
         lv, li = X.exchange_local(codec, sv, si)
         mesh = Mesh(np.array(jax.devices()[:1]), ("workers",))
-        f = lambda v, i: X.exchange_dist(codec, v[0], i[0], "workers")
-        dv, di = jax.jit(shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+        on_mesh = dataclasses.replace(codec, axis_name="workers")
+        f = lambda v, i: X.exchange_local(on_mesh, v, i)
+        dv, di = jax.jit(shard_map(f, mesh=mesh, in_specs=P("workers"),
+                                   out_specs=P("workers"),
                                    check_vma=False))(sv, si)
-        np.testing.assert_array_equal(np.asarray(lv[0]), np.asarray(dv))
-        np.testing.assert_array_equal(np.asarray(li[0]), np.asarray(di))
+        assert dv.dtype == lv.dtype and di.dtype == li.dtype
+        np.testing.assert_array_equal(np.asarray(lv), np.asarray(dv))
+        np.testing.assert_array_equal(np.asarray(li), np.asarray(di))
 
 
 class TestElastic:

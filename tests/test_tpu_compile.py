@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro.configs import get_graph_config
 from repro.configs.base import GraphConfig
@@ -198,3 +199,42 @@ def test_mesh_tick_exchanges_all_to_all(topo):
     compiled, info = E.lower_tick_for_mesh(cfg, mesh, 4)
     assert info["workers"] == 4
     assert "all-to-all" in compiled.as_text()
+
+
+def test_mesh_tick_crosses_chips_only_under_wire_on_v5e(topo):
+    """The four-chip cell's tick (``g500-s17-wcc-x4``: 8 shards of 16,384
+    vertices, the largest holding 487,573 edges, two shards on each chip of
+    a 2x2 v5e): the session's tick over the mesh has its ``all-to-all``
+    ops only under ``tick.wire``, and each chip's temporaries take under
+    half of it."""
+    mesh = Mesh(np.asarray(topo.devices), ("workers",))
+    cfg = GraphConfig(name="cell", algorithm="cc", num_vertices=1 << 17,
+                      avg_degree=16, num_shards=8)
+    prog = PR.get_program(cfg)
+    P_, vs, es = 8, 16384, 487573
+    ep = E.derive_params(cfg, num_shards=P_, vs=vs, es=es,
+                         num_vertices=cfg.num_vertices, prog=prog)
+    assert (ep.max_vertices_per_tick, ep.route_capacity) == (7618, 19045)
+    rows, whole = NamedSharding(mesh, P("workers")), NamedSharding(mesh, P())
+
+    def spec(shape, dtype, sharding=rows):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    state = E.EngineState(spec((P_, vs), prog.jdtype),
+                          spec((P_, vs), jnp.bool_),
+                          spec((P_, vs), jnp.int32),
+                          spec((), jnp.int32, whole), None)
+    g = E.ShardGraph(spec((P_, vs + 1), jnp.int32),
+                     spec((P_, es), jnp.int32), None)
+    compiled = jax.jit(E.shard_local_tick(prog, ep, mesh, False)).lower(
+        state, g).compile()
+    hlo = compiled.as_text()
+    a2a = [ln for ln in hlo.splitlines()
+           if re.search(r"= \S+ all-to-all(-start)?\(", ln)]
+    # the values and the ids, each [2 shards, 8 destinations, cap] a chip
+    assert len(a2a) == 2, a2a
+    for ln in a2a:
+        assert "s32[2,8,19045]" in ln, ln
+        assert _scope(re.search(r'op_name="([^"]*)"', ln)[1]) == "tick.wire"
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes < HBM_BYTES // 2
