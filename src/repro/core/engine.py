@@ -12,7 +12,9 @@ One tick per shard (Fig 1 / Fig 2 mapped to SPMD):
                (backpressure); one all_to_all delivers everything
   receive    — idempotent scatter-⊕ via the program's Aggregator (min for
                CC/SSSP/BFS, max for widest-path/labelprop, or for
-               reachability); improved vertices join the frontier
+               reachability); improved vertices join the frontier.  The
+               plain tick reads only the occupied prefix of the receive
+               buffers, in one of a few static widths picked per tick
 
 One tick builder, two placements of the same per-shard code:
   one device — arrays [P, ...], vmap + transpose as the exchange
@@ -68,6 +70,8 @@ class TickStats(NamedTuple):
     sent: jnp.ndarray  # messages sent
     accepted: jnp.ndarray  # messages that improved a value
     fetched: jnp.ndarray  # edges fetched (seek rate, Fig 10)
+    # receive-buffer slots the receive processed (the plain tick only)
+    recv_slots: Optional[jnp.ndarray] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -402,6 +406,54 @@ def _phase2_receive(prog, ep: EngineParams, values, active, cursor,
     return values, active, cursor, accepted
 
 
+def receive_widths(cap: int) -> tuple[int, ...]:
+    """The static widths of the idempotent receive, narrowest first:
+    ``cap``, and about ``cap / 8`` and ``cap / 64`` rounded up to whole
+    128-lane rows where that is narrower than ``cap``."""
+    def lanes(n):
+        return -(-n // 128) * 128
+    return tuple(sorted({cap} | {w for w in (lanes(-(-cap // 8)),
+                                             lanes(-(-cap // 64)))
+                                 if w < cap}))
+
+
+def _receive_prefix(prog, ep: EngineParams, values, active, cursor, rv, ri):
+    """The idempotent receive over a block of shards (``rv``, ``ri``
+    ``[Pb, Pn, cap]``), run on the occupied prefix of every row only: the
+    narrowest of :func:`receive_widths` that holds each valid id of the
+    block, picked on the device.  Every message lies in the prefix, so the
+    result is the full-width receive's.  Returns ``(values, active,
+    cursor, accepted [Pb], slots)``, ``slots`` the receive slots
+    processed.
+
+    The branch is taken outside the ``vmap`` over the block: under it a
+    ``switch`` would run every branch.  The ``switch`` itself stays out of
+    the ``tick.receive`` scope that its branches and the extent carry: a
+    device trace shows the conditional as one op that spans the ops of
+    the branch it ran, which the scope would count twice."""
+    p2v = jax.vmap(lambda v, a, c, rvals, rids: _phase2_receive(
+        prog, ep, v, a, c, rvals, rids))
+    Pb, Pn, cap = ri.shape
+    widths = receive_widths(cap)
+    if len(widths) == 1:
+        return (*p2v(values, active, cursor, rv, ri),
+                jnp.int32(Pb * Pn * cap))
+    with jax.named_scope("tick.receive"):
+        # one past the last position of a valid id in any row
+        extent = jnp.max(jnp.where(
+            ri >= 0, jnp.arange(1, cap + 1, dtype=jnp.int32), 0))
+        which = jnp.sum(extent > jnp.asarray(widths[:-1], jnp.int32))
+        slots = jnp.asarray(widths, jnp.int32)[which] * (Pb * Pn)
+
+    def prefix(C, v, a, c, rvals, rids):
+        with jax.named_scope("tick.receive"):
+            return p2v(v, a, c, rvals[..., :C], rids[..., :C])
+
+    out = jax.lax.switch(which, [partial(prefix, C) for C in widths],
+                         values, active, cursor, rv, ri)
+    return (*out, slots)
+
+
 @jax.named_scope("tick.receive")
 def _phase2_receive_push(prog, ep: EngineParams, residual, active,
                          recv_vals, recv_ids):
@@ -468,14 +520,13 @@ def make_local_tick(prog, ep: EngineParams, weighted: bool):
                 prog, ep, res, a, rvals, rids))
             residual, active, accepted = p2v(aux[:, 0], active, rv, ri)
             aux = aux.at[:, 0].set(residual)
+            recv_slots = jnp.int32(ri.size)
         else:
-            p2v = jax.vmap(lambda v, a, c, rvals, rids:
-                           _phase2_receive(prog, ep, v, a, c, rvals, rids))
-            values, active, cursor, accepted = p2v(values, active, cursor,
-                                                   rv, ri)
+            values, active, cursor, accepted, recv_slots = _receive_prefix(
+                prog, ep, values, active, cursor, rv, ri)
             aux = state.aux  # None (or an untouched caller-supplied plane)
         stats = TickStats(jnp.sum(active), jnp.sum(sent), jnp.sum(accepted),
-                          jnp.sum(fetched))
+                          jnp.sum(fetched), recv_slots)
         if ep.mesh_axis is not None:
             stats = jax.lax.psum(stats, ep.mesh_axis)
         return (EngineState(values, active, cursor, state.tick + 1, aux),
@@ -516,8 +567,8 @@ def shard_local_tick(prog, ep: EngineParams, mesh: Mesh, weighted: bool):
     def tick(state: EngineState, g: ShardGraph):
         return shard_map(
             body, mesh=mesh, in_specs=(specs(state), specs(g)),
-            out_specs=(specs(state), TickStats(P(), P(), P(), P()),
-                       (rows, rows)),
+            out_specs=(specs(state), TickStats(*[P()] * len(
+                TickStats._fields)), (rows, rows)),
             check_vma=False)(state, g)
 
     return tick
@@ -1107,7 +1158,11 @@ class EngineSession:
         self.log: list = []
         self.totals = {"ticks": 0, "sent": 0, "accepted": 0, "fetched": 0,
                        "replayed": 0, "failures": 0, "pending": 0,
-                       "schedule": schedule}
+                       "recv_slots": 0, "schedule": schedule}
+        # the plain tick's receive slots, summed on the device over the
+        # ticks since the last pull (None: none), and those ticks
+        self._recv_slots = None
+        self._recv_ticks = 0
         self._t = 0  # host step counter (fault schedules key on it)
         self._pending = 0
         self._ring_ckpt = None
@@ -1386,13 +1441,20 @@ class EngineSession:
         t, fault_plan, fault_mgr = self._t, self.fault_plan, self.fault_mgr
         with trace.span(trace.DISPATCH):
             state, stats, send_bufs = self._tick_fn(self._state, self.g)
+        self._count_recv_slots(stats.recv_slots)
         totals = self.totals
-        with trace.span(trace.SYNC, pulls=4):
+        with trace.span(trace.SYNC) as span:
             n_active = int(stats.active)
             totals["ticks"] += 1
             totals["sent"] += int(stats.sent)
-            totals["accepted"] += int(stats.accepted)
+            # a message that improves a value leaves its vertex active, so
+            # an idempotent tick that leaves none active accepted none
+            pull_accepted = (n_active > 0
+                             or not self.prog.aggregator.idempotent)
+            accepted = int(stats.accepted) if pull_accepted else 0
+            totals["accepted"] += accepted
             totals["fetched"] += int(stats.fetched)
+            span.set_metadata(pulls=3 + pull_accepted)
         if fault_mgr is not None:
             fault_mgr.record(t, state, send_bufs)
             state, extra = fault_mgr.maybe_fail(t, state, fault_plan)
@@ -1404,10 +1466,37 @@ class EngineSession:
         if self.collect_log:  # the ints were pulled above
             self.log.append({"tick": t, "active": n_active,
                              "sent": int(stats.sent),
-                             "accepted": int(stats.accepted),
+                             "accepted": accepted,
                              "fetched": int(stats.fetched)})
         self._state = state
         self._n_active = n_active
+
+    def _count_recv_slots(self, slots) -> None:
+        """Adds a tick's receive slots to the sum kept on the device,
+        pulling it first where one more tick could overflow its int32."""
+        ep = self.ep_run
+        if (self._recv_ticks + 1) * ep.num_shards ** 2 * ep.route_capacity \
+                >= 2 ** 31:
+            self._pull_recv_slots()
+        if self._recv_slots is None:  # placed as the tick's counts are, so
+            # that the add compiles on the first tick and never again
+            self._recv_slots = self._place(jnp.zeros((), jnp.int32))
+        self._recv_slots = self._recv_slots + slots
+        self._recv_ticks += 1
+
+    def _pull_recv_slots(self) -> None:
+        """The one pull of the receive slots summed since the last, into
+        ``totals["recv_slots"]``; its span also gives the slots that a
+        full-width receive would have processed (``recv_budget``)."""
+        if self._recv_slots is None:
+            return
+        ep = self.ep_run
+        budget = self._recv_ticks * ep.num_shards ** 2 * ep.route_capacity
+        with trace.span(trace.TOTALS, pulls=1) as span:
+            slots = int(self._recv_slots)
+            span.set_metadata(recv_slots=slots, recv_budget=budget)
+        self.totals["recv_slots"] += slots
+        self._recv_slots, self._recv_ticks = None, 0
 
     # -- public surface ------------------------------------------------
     @property
@@ -1461,7 +1550,10 @@ class EngineSession:
         return self.totals_snapshot()
 
     def totals_snapshot(self) -> dict:
-        """The metrics dict ``run_to_convergence`` has always returned."""
+        """The metrics dict ``run_to_convergence`` has always returned,
+        with the receive slots that the device counted since the last
+        snapshot pulled into it."""
+        self._pull_recv_slots()
         out = dict(self.totals)
         if self.schedule == "async":
             out["pending"] = self._pending
@@ -1510,6 +1602,7 @@ class EngineSession:
         new._pending = self._pending
         new._ring_ckpt = self._ring_ckpt
         new._t = self._t
+        new._recv_slots, new._recv_ticks = self._recv_slots, self._recv_ticks
         new.totals = dict(self.totals)
         new.log = list(self.log)
         return new

@@ -15,6 +15,7 @@ import jax
 STEP = "asymp:session.step"          # one engine tick; arg tick
 DISPATCH = "asymp:session.dispatch"  # the call of the jitted tick
 SYNC = "asymp:session.sync"          # the step's device->host pulls; pulls
+TOTALS = "asymp:session.totals"      # receive slots' pull; pulls, recv_*
 LOG = "asymp:recovery.log"           # message-log pull; pulls, bytes
 SNAPSHOT = "asymp:recovery.snapshot"  # checkpoint pull; pulls, bytes
 KILL = "asymp:recovery.kill"         # a tick that kills; pulls, bytes, replayed

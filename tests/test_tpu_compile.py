@@ -171,6 +171,27 @@ def test_route_ranks_without_sort_gather_or_scatter_on_v5e(cell_tick):
     assert {_scope(p) for p in paths} == {"tick.route"}
 
 
+def test_receive_takes_three_widths_on_v5e(cell_tick):
+    """The cell's receive is one ``conditional`` over three static widths
+    of its buffers (256, 1,280 and the whole 9,761 slots of a row): in each
+    branch, under ``tick.receive``, the gather of the old values reads ``P
+    x width`` slots a shard and one scatter updates the values.  The
+    conditional, whose device op spans its branch's ops, is under no
+    phase's scope, so that the phase does not count them twice."""
+    ep, hlo = cell_tick
+    P_, widths = ep.num_shards, E.receive_widths(ep.route_capacity)
+    assert widths == (256, 1280, ep.route_capacity)
+    receive = sorted((op, ty) for sc, op, ty in _scoped_ops(hlo)
+                     if sc == "tick.receive")
+    assert receive == sorted(
+        [("gather", f"s32[{P_},{P_ * w}]") for w in widths]
+        + [("scatter", f"s32[{P_ * ep.vs}]")] * len(widths))
+    conds = re.findall(r"= .+? conditional\(.*branch_computations=\{(.*?)\}"
+                       r'.*op_name="([^"]*)"', hlo)
+    assert [(len(b.split(",")), _scope(path)) for b, path in conds] == [
+        (3, None)]
+
+
 def test_route_rank_fits_the_production_mesh_on_v5e(one_chip):
     """The one-hot of the counting rank is shards times the slot plane.  At
     the 256-worker dry-run of ``asymp_cc_prod`` (rmat26) one chip's rank

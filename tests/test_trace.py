@@ -4,9 +4,9 @@ A scale-9 session runs to quiescence under ``jax.profiler``, healthy and
 under a ``FaultPlan``; the ``asymp:`` events of its trace must follow the
 interface of ``docs/ARCHITECTURE.md`` ("Spans and counters"): one step span
 per tick, each pull of the step counted once in a span's ``pulls``, the
-message log's ``bytes`` equal to the send buffers', and recovery spans only
-where a plan kills shards.  The profiler must not change what the session
-computes.
+receive slots pulled once per run in a totals span, the message log's
+``bytes`` equal to the send buffers', and recovery spans only where a plan
+kills shards.  The profiler must not change what the session computes.
 """
 import glob
 import os
@@ -20,7 +20,8 @@ from repro.core import engine as E
 from repro.core import trace
 from repro.core.faults import FaultPlan
 
-# pulls of the step's sync spans on a tick that kills nothing
+# pulls a tick on a tick that kills nothing (on the plain schedule the
+# last tick's pull of ``accepted``, 0 there, is the totals span's instead)
 SYNC_PULLS = {"plain": 4, "crowded": 5, "async": 8}
 PLAN = dict(fail_fraction=0.5, start_tick=4, every=6, seed=11)
 
@@ -98,7 +99,44 @@ def test_healthy_tick_pulls_are_counted_once(tmp_path, schedule):
     assert steps == totals["ticks"] - 1
     pulls = sum(s[3].get("pulls", 0) for s in spans)
     assert pulls == SYNC_PULLS[schedule] * steps
-    assert {s[0] for s in spans} == {trace.STEP, trace.DISPATCH, trace.SYNC}
+    totals_span = {trace.TOTALS} if schedule == "plain" else set()
+    assert {s[0] for s in spans} == {trace.STEP, trace.DISPATCH,
+                                     trace.SYNC} | totals_span
+
+
+def test_receive_slots_are_pulled_once_a_call(healthy):
+    session, totals, spans = healthy
+    ep = session.ep
+    (pull,) = _named(spans, trace.TOTALS)
+    assert pull[3] == {"pulls": 1, "recv_slots": totals["recv_slots"],
+                       "recv_budget": totals["ticks"] * ep.num_shards ** 2
+                       * ep.route_capacity}
+    assert 0 < totals["recv_slots"] <= pull[3]["recv_budget"]
+    # it follows the last tick, whose sync skips the pull of ``accepted``
+    syncs = _named(spans, trace.SYNC)
+    assert [s[3]["pulls"] for s in syncs] == [4] * (len(syncs) - 1) + [3]
+    assert pull[1] >= syncs[-1][2]
+
+
+def test_each_call_pulls_its_receive_slots(tmp_path):
+    session = _session("plain", False)
+    session.step()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    calls = [session.tick_until_quiescent(5) for _ in range(3)]
+    session.tick_until_quiescent()  # the rest
+    quiet = session.tick_until_quiescent()  # quiescent: no tick, no pull
+    jax.profiler.stop_trace()
+    pulls = _named(_spans(str(tmp_path)), trace.TOTALS)
+    assert len(pulls) == 4 and all(s[3]["pulls"] == 1 for s in pulls)
+    per_tick = session.ep.num_shards ** 2 * session.ep.route_capacity
+    # the first call's pull also holds the step before the trace
+    assert [s[3]["recv_budget"] for s in pulls[:3]] == [
+        6 * per_tick, 5 * per_tick, 5 * per_tick]
+    assert sum(s[3]["recv_slots"] for s in pulls) == quiet["recv_slots"]
+    assert calls[0]["recv_slots"] < calls[1]["recv_slots"] \
+        < calls[2]["recv_slots"] < quiet["recv_slots"]
 
 
 def test_recovery_log_bytes_equal_the_send_buffers(killed):
@@ -123,8 +161,9 @@ def test_kill_spans_count_the_kills_and_their_replay(killed):
     assert totals["failures"] == len(kills) == 4
     assert sum(s[3]["replayed"] for s in kills) == totals["replayed"] > 0
     assert all(s[3]["pulls"] >= 3 and s[3]["bytes"] > 0 for s in kills)
-    # each kill re-counts the active frontier once, in its own sync span
-    syncs = _named(spans, trace.SYNC)
+    # each kill re-counts the active frontier once, in its own sync span;
+    # the last tick's pull of ``accepted`` is the totals span's instead
+    syncs = _named(spans, trace.SYNC) + _named(spans, trace.TOTALS)
     assert sum(s[3]["pulls"] for s in syncs) == 4 * (totals["ticks"] - 1) \
         + len(kills)
 
